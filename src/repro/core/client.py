@@ -37,6 +37,7 @@ def make_local_update_fn(loss_fn: Callable, local_steps: int, local_lr: float,
     opt = sgd(local_lr, momentum=momentum)
     grad_fn = jax.grad(lambda p, b: loss_fn(p, b)[0])
 
+    @jax.named_scope("local_update")
     def local_update(base_params, batches, _key=None):
         opt_state = opt.init(base_params)
 

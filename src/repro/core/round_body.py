@@ -98,10 +98,15 @@ def make_round_body(loss_fn: Callable, fl: FLConfig, *,
     mode, interpret = resolve_mode(fl.server_pass_mode)
     data_shards = mesh_axis_size(mesh, DATA_AXIS)
 
+    @jax.named_scope("probe")
+    def probe_losses(params, probe):
+        """eq. 4: the current model's loss on each client's probe batch."""
+        losses = jax.vmap(lambda pb: loss_fn(params, pb)[0])(probe)
+        return losses.astype(jnp.float32)
+
     def engine_phase(params, bases, batch, probe):
         deltas, _ = jax.vmap(local_update)(bases, batch)
-        losses = jax.vmap(lambda pb: loss_fn(params, pb)[0])(probe)
-        return deltas, losses.astype(jnp.float32)
+        return deltas, probe_losses(params, probe)
 
     def cohort_phase(params, client_params, bases, batch, probe):
         # in-flight slots advance M steps from their CURRENT local state
@@ -111,9 +116,7 @@ def make_round_body(loss_fn: Callable, fl: FLConfig, *,
                                   client_params)
         # cumulative upload delta measured from the pulled base (Delta_i)
         up_delta = jax.vmap(tree_sub)(bases, end_params)
-        losses = jax.vmap(lambda pb: loss_fn(params, pb)[0],
-                          in_axes=(0,))(probe)
-        return up_delta, end_params, losses.astype(jnp.float32)
+        return up_delta, end_params, probe_losses(params, probe)
 
     def sharded_over_clients(phase, params, *stacked):
         """Run ``phase`` with its K-stacked args/results over ``data``."""
@@ -222,7 +225,8 @@ def make_ring_round(loss_fn: Callable, fl: FLConfig, *,
 
     def ring_round(params, ring, slots, batch, probe, sizes, taus, new_slot):
         spec = make_flat_spec(params, fl.server_pass_block_n, mesh=mesh)
-        bases = codec.decode(spec, ring, slots)  # (K, n_padded) flat rows
+        with jax.named_scope("ring_decode"):
+            bases = codec.decode(spec, ring, slots)  # (K, n_padded) rows
         dists = None
         if codec.precomputes_distance:  # f32 leaves eq. 3 to the server
             # pass (the exact pre-codec program — nothing extra traced)
@@ -232,7 +236,8 @@ def make_ring_round(loss_fn: Callable, fl: FLConfig, *,
         new_params, new_x, info = body(params, bases, batch, probe, sizes,
                                        taus, flat_bases=True,
                                        return_flat=True, sq_dists=dists)
-        new_ring = codec.encode(spec, ring, new_slot, new_x)
+        with jax.named_scope("ring_encode"):
+            new_ring = codec.encode(spec, ring, new_slot, new_x)
         return new_params, new_ring, info
 
     return ring_round
@@ -305,7 +310,8 @@ def make_streaming_round_body(loss_fn: Callable,
         delta, _ = local_update(params, batch)
 
         # eq. 4 probe of the CURRENT model
-        fresh = loss_fn(params, probe)[0].astype(jnp.float32)
+        with jax.named_scope("probe"):
+            fresh = loss_fn(params, probe)[0].astype(jnp.float32)
         p = statistical_effect(fresh[None], data_size[None])
 
         # eq. 3 distance via the scalar update-norm ring (cross terms

@@ -219,6 +219,7 @@ def unflatten_like(spec: FlatSpec, vec: jnp.ndarray, template: Any) -> Any:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("server_pass")
 def apply_server_round(x: jnp.ndarray, bases: jnp.ndarray,
                        deltas: jnp.ndarray, losses: jnp.ndarray,
                        data_sizes: jnp.ndarray, taus: jnp.ndarray,

@@ -59,6 +59,7 @@ def _int8_sq_dist_kernel(x_ref, c_ref, s_ref, z_ref, o_ref, *, qblock: int):
     o_ref[...] += part
 
 
+@jax.named_scope("int8_sq_dists_pallas")
 def int8_sq_dists_pallas(x: jnp.ndarray, codes: jnp.ndarray,
                          scales: jnp.ndarray, zeros: jnp.ndarray, *,
                          qblock: int, block_n: int = DEFAULT_BLOCK_N,

@@ -63,6 +63,7 @@ def _weighted_sum_kernel(d_ref, w_ref, o_ref):
     o_ref[...] = jnp.sum(d * w, axis=0)
 
 
+@jax.named_scope("weighted_sum_pallas")
 def weighted_sum_pallas(deltas: jnp.ndarray, weights: jnp.ndarray,
                         block_n: int = DEFAULT_BLOCK_N,
                         interpret: bool = False) -> jnp.ndarray:
@@ -154,6 +155,9 @@ def _fused_server_kernel(x_ref, b_ref, d_ref, p_ref, tau_ref, m_ref,
         upd_ref[...] = jnp.sum(d_ref[...] * (w_ref[...] * scale), axis=0)
 
 
+# each kernel's scope names its custom call in the compiled program,
+# whatever jitted function the caller puts around it
+@jax.named_scope("fused_server_pallas")
 def fused_server_pallas(x: jnp.ndarray, bases: jnp.ndarray,
                         deltas: jnp.ndarray, p_stat: jnp.ndarray,
                         taus: jnp.ndarray, arrival_mask: jnp.ndarray,
@@ -233,6 +237,7 @@ def _sq_dist_kernel(x_ref, b_ref, o_ref):
     o_ref[...] += part
 
 
+@jax.named_scope("sq_dists_pallas")
 def sq_dists_pallas(x: jnp.ndarray, bases: jnp.ndarray,
                     block_n: int = DEFAULT_BLOCK_N,
                     interpret: bool = False) -> jnp.ndarray:

@@ -7,7 +7,8 @@ Three dependency-free pieces every runner reports into:
   coordinator-gated multihost merge path;
 * ``obs.trace`` — round-lifecycle span tracer emitting Chrome-trace
   JSON, each span doubling as a ``jax.profiler.TraceAnnotation`` so
-  windowed device profiles line up with host spans;
+  device profiles line up with host spans, and the engines' bounded
+  default recorder (``default_tracer``);
 * ``obs.sink`` — JSONL event sink (coordinator-gated) plus the repo's
   stdlib-logging configurator.
 """
@@ -33,10 +34,14 @@ from repro.obs.trace import (  # noqa: F401
     SPAN_CHECKPOINT,
     SPAN_COLLECT,
     SPAN_CONTRIBUTE,
+    SPAN_GATHER,
     SPAN_HOST_SYNC,
     SPAN_NAMES,
+    SPAN_PREPARE,
+    SPAN_STAGE,
     Tracer,
     WindowedProfiler,
+    default_tracer,
     span_coverage,
     validate_trace,
 )

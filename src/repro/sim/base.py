@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.sim.scenarios import ClientBehavior, LatencyModel, Scenario
@@ -40,6 +41,14 @@ class SimResult:
             if h.get(metric, -np.inf) >= target:
                 return h["time"]
         return None
+
+
+def host_nbytes(tree) -> int:
+    """Bytes of the host arrays in ``tree`` (device arrays count 0): what
+    placing ``tree`` on the device copies — the ``bytes`` arg of the
+    engines' ``prepare`` and ``stage`` spans."""
+    return int(sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree)
+                   if not isinstance(leaf, jax.Array)))
 
 
 def record_eval(history: List[Dict], eval_fn, version: int, now: float,

@@ -50,7 +50,6 @@ from __future__ import annotations
 
 import functools
 import heapq
-import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -60,12 +59,15 @@ import numpy as np
 from repro.configs.base import FLConfig
 from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.obs.trace import (
-    NULL_TRACER,
     SPAN_APPLY,
     SPAN_CHECKPOINT,
     SPAN_COLLECT,
+    SPAN_GATHER,
     SPAN_HOST_SYNC,
+    SPAN_PREPARE,
+    SPAN_STAGE,
     Tracer,
+    default_tracer,
 )
 from repro.core.round_body import make_ring_round
 from repro.core.server_pass import flatten_tree, make_flat_spec
@@ -79,6 +81,7 @@ from repro.sim.base import (  # noqa: F401  (re-exported for callers)
     SimResult,
     history_from_arrays,
     history_to_arrays,
+    host_nbytes,
     make_batches,
     record_eval,
     resolve_behavior,
@@ -261,7 +264,6 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
     """
     n = len(clients)
     k = fl.buffer_size
-    beh = resolve_behavior(n, seed, behavior, scenario, latency, trace)
     ring_depth = fl.max_staleness + 1
     spans = mesh_spans_processes(mesh)
 
@@ -271,92 +273,106 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
     # launch-count gate reads via SimResult.num_launches — cannot miss a
     # dispatch site the way a hand-maintained `num_launches += 1` could
     # (e.g. a future final-eval or warmup path calling chunk_step
-    # directly). The histogram records host-side dispatch time only (no
-    # block_until_ready: the engine deliberately runs ahead of the
-    # device), so it measures the launch overhead the O(T/S) contract
-    # bounds, not device compute.
+    # directly). Each call records its phases as spans (``prepare``,
+    # then per launch ``collect_window``, ``gather``, ``stage``,
+    # ``apply``, then ``host_sync`` and ``checkpoint``)
+    # into the process's bounded default recorder unless a tracer is
+    # passed.
     reg = registry if registry is not None else default_registry()
-    tr = tracer if tracer is not None else NULL_TRACER
+    tr = tracer if tracer is not None else default_tracer()
     _dispatches = reg.counter("engine_dispatches_total")
-    _launch_hist = reg.histogram("engine_launch_seconds")
     _syncs = reg.counter("engine_host_syncs_total")
     _dispatches_start = _dispatches.value
-    _raw_chunk_step = _make_chunk_step(loss_fn, fl, mesh)
+
+    with tr.span(SPAN_PREPARE, version=0 if init_state is None
+                 else init_state.version) as prep:
+        _raw_chunk_step = _make_chunk_step(loss_fn, fl, mesh)
+        beh = resolve_behavior(n, seed, behavior, scenario, latency, trace)
+        if init_state is None:
+            params = init_params
+            _, ring = init_version_ring(init_params, fl, mesh=mesh,
+                                        shard_ring=shard_ring)
+            version = 0
+            base_version = np.zeros(n, np.int64)
+            now = 0.0
+            history: List[Dict] = []
+            round_log_prefix: List[Dict] = []
+            num_events = 0
+            # every client starts training at t=0 (availability-gated)
+            # from v0
+            events = []
+            for cid in range(n):
+                start = beh.next_start(cid, 0.0)
+                events.append((start + beh.duration(cid, start), cid))
+            heapq.heapify(events)
+            put_bytes = host_nbytes(params)
+        else:
+            if record_trace:
+                raise ValueError(
+                    "record_trace cannot resume from a checkpoint: the "
+                    "duration draws before the snapshot are not in the "
+                    "restored state")
+            if len(init_state.base_version) != n:
+                raise ValueError(
+                    f"checkpoint has {len(init_state.base_version)} "
+                    f"clients, this run has {n}")
+            beh.set_state(init_state.behavior)
+            for c, row in zip(clients, init_state.dataset_rng):
+                c.set_rng_state(row)
+            params = init_state.params
+            _, ring = init_version_ring(init_params, fl, mesh=mesh,
+                                        shard_ring=shard_ring,
+                                        rows=init_state.ring)
+            version = init_state.version
+            base_version = np.asarray(init_state.base_version,
+                                      np.int64).copy()
+            now = init_state.now
+            history = [dict(h) for h in init_state.history]
+            if eval_fn and history and history[-1]["round"] == version \
+                    and version % eval_every:
+                # the snapshot run's trailing FORCED eval: off the
+                # cadence, the uninterrupted run never evaluates here —
+                # drop it so the resumed history matches bit-for-bit
+                history.pop()
+            round_log_prefix = [dict(r) for r in init_state.round_log]
+            num_events = init_state.num_events
+            events = [(float(t), int(c)) for t, c in init_state.events]
+            heapq.heapify(events)
+            put_bytes = host_nbytes((params, init_state.ring))
+        if mesh is not None:
+            # params live replicated on the mesh (the flat vector and the
+            # K-client axis are re-partitioned inside the round's
+            # shard_maps)
+            params = (put_replicated(params, mesh) if spans
+                      else jax.device_put(params, jax.sharding.NamedSharding(
+                          mesh, jax.sharding.PartitionSpec())))
+        # bytes of the ring rows placed here and of host params, which
+        # the first launch copies
+        prep.set(bytes=put_bytes)
 
     def chunk_step(*args):
-        t0 = time.perf_counter()
         _dispatches.inc()
-        out = _raw_chunk_step(*args)
-        _launch_hist.observe(time.perf_counter() - t0)
-        return out
+        return _raw_chunk_step(*args)
 
-    if init_state is None:
-        params = init_params
-        _, ring = init_version_ring(init_params, fl, mesh=mesh,
-                                    shard_ring=shard_ring)
-        version = 0
-        base_version = np.zeros(n, np.int64)
-        now = 0.0
-        history: List[Dict] = []
-        round_log_prefix: List[Dict] = []
-        num_events = 0
-        # every client starts training at t=0 (availability-gated) from v0
-        events = []
-        for cid in range(n):
-            start = beh.next_start(cid, 0.0)
-            events.append((start + beh.duration(cid, start), cid))
-        heapq.heapify(events)
-    else:
-        if record_trace:
-            raise ValueError(
-                "record_trace cannot resume from a checkpoint: the duration "
-                "draws before the snapshot are not in the restored state")
-        if len(init_state.base_version) != n:
-            raise ValueError(
-                f"checkpoint has {len(init_state.base_version)} clients, "
-                f"this run has {n}")
-        beh.set_state(init_state.behavior)
-        for c, row in zip(clients, init_state.dataset_rng):
-            c.set_rng_state(row)
-        params = init_state.params
-        _, ring = init_version_ring(init_params, fl, mesh=mesh,
-                                    shard_ring=shard_ring,
-                                    rows=init_state.ring)
-        version = init_state.version
-        base_version = np.asarray(init_state.base_version, np.int64).copy()
-        now = init_state.now
-        history = [dict(h) for h in init_state.history]
-        if eval_fn and history and history[-1]["round"] == version \
-                and version % eval_every:
-            # the snapshot run's trailing FORCED eval: off the cadence,
-            # the uninterrupted run never evaluates here — drop it so
-            # the resumed history matches bit-for-bit
-            history.pop()
-        round_log_prefix = [dict(r) for r in init_state.round_log]
-        num_events = init_state.num_events
-        events = [(float(t), int(c)) for t, c in init_state.events]
-        heapq.heapify(events)
-    if mesh is not None:
-        # params live replicated on the mesh (the flat vector and the
-        # K-client axis are re-partitioned inside the round's shard_maps)
-        params = (put_replicated(params, mesh) if spans
-                  else jax.device_put(params, jax.sharding.NamedSharding(
-                      mesh, jax.sharding.PartitionSpec())))
     pending: List[Dict] = []  # per-round host metadata + device info handles
     event_log: List = []
 
     def maybe_eval(force=False):
-        record_eval(history, eval_fn, version, now, params, eval_every,
-                    force)
+        if eval_fn is None or not (force or version % eval_every == 0):
+            return
+        with tr.span(SPAN_HOST_SYNC, what="eval", version=version):
+            record_eval(history, eval_fn, version, now, params, eval_every,
+                        force)
 
     def reschedule(cid, t):
         start = beh.next_start(cid, t)
         heapq.heappush(events, (start + beh.duration(cid, start), cid))
 
-    def collect_window():
+    def walk_window():
         """Pop exactly K accepted uploads; the host event loop runs ahead
         of the device, which is legal because upload times never depend
-        on server state. Returns the stacked per-window arrays."""
+        on server state. Returns the window as (t, cid, base_version,
+        tau) rows."""
         nonlocal num_events, now
         window: List = []  # (t, cid, base_version, tau)
         while len(window) < k:
@@ -380,21 +396,22 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
                 base_version[cid] = version
                 reschedule(cid, t)
         now = window[-1][0]  # the K-th upload triggers the aggregation
-        # one vectorized gather per client for the M local-step batches
-        # (ClientDataset.batches draws the same index stream as M
-        # sequential .batch() calls); probes draw AFTER all train draws —
-        # the aggregation-time order AsyncServer uses — so legacy parity
-        # holds. The per-step Python loops this replaces were the host
-        # bottleneck at large N.
+        return window
+
+    def gather(window):
+        """The window's client batches, stacked (K, ...). One vectorized
+        gather per client for the M local-step batches
+        (ClientDataset.batches draws the same index stream as M
+        sequential .batch() calls); probes draw AFTER all train draws —
+        the aggregation-time order AsyncServer uses — so legacy parity
+        holds. Gathering a launch's windows after walking them keeps
+        each client's draws in order: the walk draws nothing from the
+        clients' batch streams."""
         train = [clients[cid].batches(fl.batch_size, fl.local_steps)
                  for _, cid, _, _ in window]
         probes = [clients[cid].batch(fl.batch_size)
                   for _, cid, _, _ in window]  # eq.-4 probes, FIFO order
         return {
-            "clients": [cid for _, cid, _, _ in window],
-            "tau": [tau for _, _, _, tau in window],
-            "t_trigger": window[-1][0],
-            "cid_trigger": window[-1][1],
             "batches": tuple(np.stack([b[i] for b in train])
                              for i in range(2)),
             "probes": tuple(np.stack([p[i] for p in probes])
@@ -416,41 +433,49 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
             horizon = min(horizon, eval_every - version % eval_every)
         s = min(rounds_per_launch, horizon)
 
-        # ---- host: pre-compute S windows of events ----------------------
+        # ---- host: walk S windows of events, then draw their batches ----
         windows = []
         with tr.span(SPAN_COLLECT, rounds=s, version=version):
             for _ in range(s):
-                w = collect_window()
+                w = walk_window()
                 version += 1
                 # window clients re-pull: the K-th gets the NEW version
-                base_version[w["cid_trigger"]] = version
-                reschedule(w["cid_trigger"], w["t_trigger"])
+                t_trigger, cid_trigger = w[-1][0], w[-1][1]
+                base_version[cid_trigger] = version
+                reschedule(cid_trigger, t_trigger)
                 windows.append(w)
+        with tr.span(SPAN_GATHER, rounds=s):
+            arrays = [gather(w) for w in windows]
 
         # ---- device: all S rounds in one scanned launch -----------------
-        chunk_args = (
-            np.stack([w["base_slots"] for w in windows]),
-            tuple(np.stack([w["batches"][i] for w in windows])
-                  for i in range(2)),
-            tuple(np.stack([w["probes"][i] for w in windows])
-                  for i in range(2)),
-            np.stack([w["sizes"] for w in windows]),
-            np.asarray([w["tau"] for w in windows], np.float32),
-            np.asarray([(version - s + j + 1) % ring_depth
-                        for j in range(s)], np.int32))
-        if spans:
-            # multi-controller: every process computed the SAME host
-            # arrays (same seeds drive the event loop), so placing them
-            # replicated across the process-spanning mesh needs no
-            # communication — each process fills its shards locally
-            chunk_args = put_replicated(chunk_args, mesh)
+        with tr.span(SPAN_STAGE, rounds=s) as stage:
+            chunk_args = (
+                np.stack([a["base_slots"] for a in arrays]),
+                tuple(np.stack([a["batches"][i] for a in arrays])
+                      for i in range(2)),
+                tuple(np.stack([a["probes"][i] for a in arrays])
+                      for i in range(2)),
+                np.stack([a["sizes"] for a in arrays]),
+                np.asarray([[tau for _, _, _, tau in w] for w in windows],
+                           np.float32),
+                np.asarray([(version - s + j + 1) % ring_depth
+                            for j in range(s)], np.int32))
+            stage.set(bytes=host_nbytes(chunk_args))
+            if spans:
+                # multi-controller: every process computed the SAME host
+                # arrays (same seeds drive the event loop), so placing
+                # them replicated across the process-spanning mesh needs
+                # no communication — each process fills its shards
+                # locally
+                chunk_args = put_replicated(chunk_args, mesh)
         with tr.span(SPAN_APPLY, rounds=s, version=version):
             params, ring, infos = chunk_step(params, ring, *chunk_args)
         # keep only the round-log metadata; the batch arrays would
         # otherwise pin O(total_rounds * K * batch) host memory
-        pending.append({"windows": [{"clients": w["clients"], "tau": w["tau"]}
-                                    for w in windows],
-                        "v_end": version, "infos": infos})
+        pending.append({"windows": [
+            {"clients": [cid for _, cid, _, _ in w],
+             "tau": [tau for _, _, _, tau in w]} for w in windows],
+            "v_end": version, "infos": infos})
         maybe_eval()
     maybe_eval(force=True)
 
@@ -468,15 +493,15 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
             fetched = fetch_replicated(infos_list)
         else:
             fetched = jax.device_get(infos_list)
-    round_log = list(round_log_prefix)
-    for meta, logs in zip(pending, fetched):
-        windows = meta["windows"]
-        round_log.extend(round_log_rows(
-            meta["v_end"] - len(windows), k,
-            [w["clients"] for w in windows],
-            [w["tau"] for w in windows], logs))
-    trace_out = (EventTrace.from_behavior(beh, event_log)
-                 if record_trace else None)
+        round_log = list(round_log_prefix)
+        for meta, logs in zip(pending, fetched):
+            windows = meta["windows"]
+            round_log.extend(round_log_rows(
+                meta["v_end"] - len(windows), k,
+                [w["clients"] for w in windows],
+                [w["tau"] for w in windows], logs))
+        trace_out = (EventTrace.from_behavior(beh, event_log)
+                     if record_trace else None)
     final_state = None
     if capture_state:
         with tr.span(SPAN_CHECKPOINT, version=version):

@@ -52,7 +52,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -71,17 +70,19 @@ from repro.launch.multihost import (
 )
 from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.obs.trace import (
-    NULL_TRACER,
     SPAN_APPLY,
     SPAN_CHECKPOINT,
     SPAN_HOST_SYNC,
+    SPAN_PREPARE,
     Tracer,
+    default_tracer,
 )
 from repro.sharding.specs import DATA_AXIS, client_state_pspec, mesh_axis_size
 from repro.sim.base import (
     SimResult,
     history_from_arrays,
     history_to_arrays,
+    host_nbytes,
     record_eval,
     round_log_from_arrays,
     round_log_rows,
@@ -380,6 +381,7 @@ def make_window_step(sc: Scenario, fl: FLConfig, n: int, seed: int,
         return new_st, {"cids": w_c, "taus": w_tau, "slots": w_slot,
                         "bk0": w_bk, "t": w_t}
 
+    @jax.named_scope("window_step")
     def window_step(statics: PopStatics, st: PopState):
         if force_exact:
             return _exact(st, statics)
@@ -969,83 +971,97 @@ def run_population(loss_fn: Callable, init_params: Any,
         raise ValueError("run_population is scenario-driven; LatencyModel "
                          "populations need the host engine "
                          "(engine='vectorized')")
-    sc = scenario if scenario is not None else Scenario(
-        name="population-default",
-        description="heterogeneous lognormal population")
-    pool = data if isinstance(data, DevicePool) else \
-        DevicePool.from_clients(data)
-    n = pool.num_clients
-    k = fl.buffer_size
-    spans = mesh_spans_processes(mesh)
-    pspec_n = _n_pspec(mesh, n)
-
     reg = registry if registry is not None else default_registry()
-    tr = tracer if tracer is not None else NULL_TRACER
+    tr = tracer if tracer is not None else default_tracer()
     _dispatches = reg.counter("engine_dispatches_total")
-    _launch_hist = reg.histogram("engine_launch_seconds")
     _syncs = reg.counter("engine_host_syncs_total")
     _dispatches_start = _dispatches.value
 
-    # ---- place the pool --------------------------------------------------
-    if mesh is not None:
-        pool_x = put_with_sharding(np.asarray(pool.x), mesh, P())
-        pool_y = put_with_sharding(np.asarray(pool.y), mesh, P())
-        offsets = put_with_sharding(np.asarray(pool.offsets, np.int32),
-                                    mesh, pspec_n)
-        sizes = put_with_sharding(np.asarray(pool.sizes, np.int32),
-                                  mesh, pspec_n)
-    else:
-        pool_x = jnp.asarray(pool.x)
-        pool_y = jnp.asarray(pool.y)
-        offsets = jnp.asarray(pool.offsets, jnp.int32)
-        sizes = jnp.asarray(pool.sizes, jnp.int32)
+    with tr.span(SPAN_PREPARE, version=0 if init_state is None
+                 else init_state.version) as prep:
+        sc = scenario if scenario is not None else Scenario(
+            name="population-default",
+            description="heterogeneous lognormal population")
+        pool = data if isinstance(data, DevicePool) else \
+            DevicePool.from_clients(data)
+        n = pool.num_clients
+        k = fl.buffer_size
+        spans = mesh_spans_processes(mesh)
+        pspec_n = _n_pspec(mesh, n)
 
-    statics = _make_statics_fn(sc, n, seed, mesh)()
+        # ---- place the pool ----------------------------------------------
+        if mesh is not None:
+            # every pool array goes through the host
+            host = (np.asarray(pool.x), np.asarray(pool.y),
+                    np.asarray(pool.offsets, np.int32),
+                    np.asarray(pool.sizes, np.int32))
+            put_bytes = host_nbytes(host)
+            pool_x = put_with_sharding(host[0], mesh, P())
+            pool_y = put_with_sharding(host[1], mesh, P())
+            offsets = put_with_sharding(host[2], mesh, pspec_n)
+            sizes = put_with_sharding(host[3], mesh, pspec_n)
+        else:
+            put_bytes = host_nbytes(tuple(pool))
+            pool_x = jnp.asarray(pool.x)
+            pool_y = jnp.asarray(pool.y)
+            offsets = jnp.asarray(pool.offsets, jnp.int32)
+            sizes = jnp.asarray(pool.sizes, jnp.int32)
 
-    # ---- init / resume ---------------------------------------------------
-    if init_state is None:
-        params = init_params
-        _, ring = init_version_ring(init_params, fl, mesh=mesh,
-                                    shard_ring=shard_ring)
-        state = _make_init_state_fn(sc, n, seed, mesh)(statics)
-        version = 0
-        history: List[Dict] = []
-        round_log_prefix: List[Dict] = []
-    else:
-        if len(init_state.base_version) != n:
-            raise ValueError(
-                f"checkpoint has {len(init_state.base_version)} clients, "
-                f"this run has {n}")
-        params = init_state.params
-        _, ring = init_version_ring(init_params, fl, mesh=mesh,
-                                    shard_ring=shard_ring,
-                                    rows=init_state.ring)
-        version = init_state.version
+        statics = _make_statics_fn(sc, n, seed, mesh)()
 
-        def _place(arr, dtype):
-            a = np.asarray(arr, dtype)
-            return put_with_sharding(a, mesh, pspec_n) if mesh is not None \
-                else jnp.asarray(a)
+        # ---- init / resume -----------------------------------------------
+        if init_state is None:
+            params = init_params
+            _, ring = init_version_ring(init_params, fl, mesh=mesh,
+                                        shard_ring=shard_ring)
+            state = _make_init_state_fn(sc, n, seed, mesh)(statics)
+            version = 0
+            history: List[Dict] = []
+            round_log_prefix: List[Dict] = []
+            put_bytes += host_nbytes(params)
+        else:
+            if len(init_state.base_version) != n:
+                raise ValueError(
+                    f"checkpoint has {len(init_state.base_version)} "
+                    f"clients, this run has {n}")
+            params = init_state.params
+            _, ring = init_version_ring(init_params, fl, mesh=mesh,
+                                        shard_ring=shard_ring,
+                                        rows=init_state.ring)
+            version = init_state.version
 
-        state = PopState(
-            t_next=_place(init_state.t_next, np.float32),
-            k_next=_place(init_state.k_next, np.int32),
-            batch_k=_place(init_state.batch_k, np.int32),
-            base_version=_place(init_state.base_version, np.int32),
-            version=jnp.int32(version),
-            now=jnp.float32(init_state.now),
-            num_events=jnp.int32(init_state.num_events))
-        history = [dict(h) for h in init_state.history]
-        if eval_fn and history and history[-1]["round"] == version \
-                and version % eval_every:
-            # drop the snapshot run's trailing forced eval (off-cadence)
-            # so the resumed history matches the uninterrupted run
-            history.pop()
-        round_log_prefix = [dict(r) for r in init_state.round_log]
-    if mesh is not None:
-        params = (put_replicated(params, mesh) if spans
-                  else jax.device_put(params, jax.sharding.NamedSharding(
-                      mesh, P())))
+            def _place(arr, dtype):
+                a = np.asarray(arr, dtype)
+                return put_with_sharding(a, mesh, pspec_n) \
+                    if mesh is not None else jnp.asarray(a)
+
+            state = PopState(
+                t_next=_place(init_state.t_next, np.float32),
+                k_next=_place(init_state.k_next, np.int32),
+                batch_k=_place(init_state.batch_k, np.int32),
+                base_version=_place(init_state.base_version, np.int32),
+                version=jnp.int32(version),
+                now=jnp.float32(init_state.now),
+                num_events=jnp.int32(init_state.num_events))
+            history = [dict(h) for h in init_state.history]
+            if eval_fn and history and history[-1]["round"] == version \
+                    and version % eval_every:
+                # drop the snapshot run's trailing forced eval
+                # (off-cadence) so the resumed history matches the
+                # uninterrupted run
+                history.pop()
+            round_log_prefix = [dict(r) for r in init_state.round_log]
+            put_bytes += host_nbytes((
+                params, init_state.ring, init_state.t_next,
+                init_state.k_next, init_state.batch_k,
+                init_state.base_version))
+        if mesh is not None:
+            params = (put_replicated(params, mesh) if spans
+                      else jax.device_put(params, jax.sharding.NamedSharding(
+                          mesh, P())))
+        # bytes of the pool, ring rows and (N,) state placed here and of
+        # host params, which the first launch copies
+        prep.set(bytes=put_bytes)
 
     def _fetch(tree):
         if any(isinstance(l, jax.Array) and not l.is_fully_addressable
@@ -1072,11 +1088,9 @@ def run_population(loss_fn: Callable, init_params: Any,
         s = min(rounds_per_launch, horizon)
         chunk = _make_pop_chunk(loss_fn, fl, sc, n, s, seed, mesh)
         with tr.span(SPAN_APPLY, rounds=s, version=version):
-            t0 = time.perf_counter()
             _dispatches.inc()
             params, ring, state, outs = chunk(params, ring, state, statics,
                                               pool_x, pool_y, offsets, sizes)
-            _launch_hist.observe(time.perf_counter() - t0)
         # the host mirrors `version` deterministically — no sync needed
         # for loop control
         version += s
@@ -1090,11 +1104,12 @@ def run_population(loss_fn: Callable, init_params: Any,
         _syncs.inc()
         fetched = _fetch(outs_list)
         state_h = _fetch(state)
-    round_log = list(round_log_prefix)
-    for meta, logs in zip(pending, fetched):
-        s_chunk = len(logs["clients"])
-        round_log.extend(round_log_rows(
-            meta["v_end"] - s_chunk, k, logs["clients"], logs["tau"], logs))
+        round_log = list(round_log_prefix)
+        for meta, logs in zip(pending, fetched):
+            s_chunk = len(logs["clients"])
+            round_log.extend(round_log_rows(
+                meta["v_end"] - s_chunk, k, logs["clients"], logs["tau"],
+                logs))
     now = float(state_h.now)
     num_events = int(state_h.num_events)
 

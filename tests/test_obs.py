@@ -3,6 +3,7 @@ histogram-quantile accuracy vs numpy, Chrome-trace schema + coverage,
 sink gating, and the migration contracts — ServingController.metrics()
 parity with the registry, the engine's dispatch counter backing
 SimResult.num_launches, and bench provenance compatibility checks."""
+import functools
 import json
 import logging
 import math
@@ -30,16 +31,25 @@ from repro.obs import (
     merge_snapshots,
 )
 from repro.obs.trace import (
+    ANNOTATION_PREFIX,
+    DEFAULT_CAPACITY,
     NULL_TRACER,
     SPAN_APPLY,
+    SPAN_CHECKPOINT,
     SPAN_COLLECT,
+    SPAN_GATHER,
+    SPAN_HOST_SYNC,
     SPAN_NAMES,
+    SPAN_PREPARE,
+    SPAN_STAGE,
     _NULL_SPAN,
+    default_tracer,
     span_coverage,
     validate_trace,
 )
 from repro.sim.arrivals import TrafficGenerator
 from repro.sim.engine import run_vectorized
+from repro.sim.population import run_population
 from repro.sim.scenarios import get_scenario
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -159,13 +169,55 @@ class TestTracer:
         tr = Tracer(annotate=False)
         with tr.span(SPAN_APPLY, version=3):
             pass
-        tr.instant("marker")
         doc = tr.to_json()
-        assert validate_trace(doc) == 2
+        assert validate_trace(doc) == 1
         ev = doc["traceEvents"][0]
         assert ev["name"] == SPAN_APPLY and ev["ph"] == "X"
         assert ev["dur"] >= 0 and ev["args"] == {"version": 3}
         assert ev["pid"] == os.getpid()
+
+    def test_bounded_tracer_keeps_the_newest_events(self):
+        tr = Tracer(annotate=False, capacity=4)
+        for i in range(10):
+            with tr.span(SPAN_APPLY, i=i):
+                pass
+        assert [ev["args"]["i"] for ev in tr.events] == [6, 7, 8, 9]
+        doc = tr.to_json()  # the export holds what the ring holds
+        assert validate_trace(doc) == 4
+        assert doc["traceEvents"][0]["args"]["i"] == 6
+
+    def test_default_tracer_is_one_bounded_recorder(self):
+        tr = default_tracer()
+        assert tr is default_tracer()
+        assert tr.enabled and tr.annotate
+        assert tr.events.maxlen == DEFAULT_CAPACITY
+
+    def test_span_args_set_inside(self):
+        tr = Tracer(annotate=False)
+        with tr.span(SPAN_STAGE, rounds=2) as sp:
+            sp.set(bytes=64)
+        assert tr.events[0]["args"] == {"rounds": 2, "bytes": 64}
+        with NULL_TRACER.span(SPAN_STAGE) as sp:
+            sp.set(bytes=64)  # a no-op on the disabled tracer
+
+    def test_annotations_reach_the_profiler_host_plane(self, tmp_path):
+        """Each span opens ``repro.<span>`` on the profiler's timeline,
+        apart from the benchmark's and JAX's own events."""
+        from jax.profiler import ProfileData
+
+        tr = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span(SPAN_APPLY):
+                jnp.ones(3).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        pd = ProfileData.from_file(str(path))
+        names = {ev.name for plane in pd.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert ANNOTATION_PREFIX + SPAN_APPLY in names
 
     def test_disabled_tracer_is_free(self):
         tr = Tracer(enabled=False)
@@ -174,8 +226,7 @@ class TestTracer:
         with tr.span("x"):
             pass
         tr.complete("x", 0.0, 1.0)
-        tr.instant("x")
-        assert tr.events == []
+        assert not tr.events
 
     def test_retroactive_complete(self):
         tr = Tracer(annotate=False)
@@ -434,13 +485,16 @@ class TestEngineDispatchCounter:
 
     def test_num_launches_is_a_registry_view(self):
         reg = MetricsRegistry()
+        tracer = Tracer(annotate=False)
         res = run_vectorized(_quad_loss, {"w": jnp.zeros(4)},
                              _quad_clients(), self.FL, total_rounds=10,
-                             seed=0, rounds_per_launch=4, registry=reg)
+                             seed=0, rounds_per_launch=4, registry=reg,
+                             tracer=tracer)
         snap = reg.snapshot()
         assert res.num_launches == 3  # ceil(10 / 4)
         assert snap["engine_dispatches_total"] == 3.0
-        assert snap["engine_launch_seconds_count"] == 3.0
+        # one apply span per dispatch
+        assert [ev["name"] for ev in tracer.events].count(SPAN_APPLY) == 3
         assert snap["engine_host_syncs_total"] >= 1.0  # the round-log fetch
 
     def test_counter_accumulates_but_result_delta_does_not(self):
@@ -466,6 +520,153 @@ class TestEngineDispatchCounter:
             by_name.setdefault(ev["name"], []).append(ev)
         assert len(by_name[SPAN_APPLY]) == 2  # one per dispatch
         assert SPAN_COLLECT in by_name and "host_sync" in by_name
+
+
+# ---------------------------------------------------------------------------
+# engine spans: the default recorder, counts as args, coverage
+# ---------------------------------------------------------------------------
+
+
+# the spans a simulator engine call is made of, entry to return
+ENGINE_SPANS = (SPAN_PREPARE, SPAN_COLLECT, SPAN_GATHER, SPAN_STAGE,
+                SPAN_APPLY, SPAN_HOST_SYNC, SPAN_CHECKPOINT)
+
+
+def _by_name(events):
+    out = {}
+    for ev in events:
+        out.setdefault(ev["name"], []).append(ev)
+    return out
+
+
+def _call_coverage(events, t0_us, t1_us):
+    """Union of the engine spans over one call's wall time [t0, t1)."""
+    ivs = sorted((ev["ts"], ev["ts"] + ev["dur"]) for ev in events
+                 if ev["name"] in ENGINE_SPANS)
+    covered, hi = 0.0, t0_us
+    for lo, end in ivs:
+        lo = max(lo, hi)
+        if end > lo:
+            covered += end - lo
+            hi = end
+    return covered / (t1_us - t0_us)
+
+
+def _timed_call(tracer, fn, **kw):
+    """Run one engine call; returns (result, its events, coverage)."""
+    start = len(tracer.events)
+    t0 = (tracer.now() - tracer._t0) * 1e6
+    res = fn(tracer=tracer, **kw)
+    t1 = (tracer.now() - tracer._t0) * 1e6
+    events = list(tracer.events)[start:]
+    return res, events, _call_coverage(events, t0, t1)
+
+
+class TestEngineSpans:
+    FL = FLConfig(num_clients=6, buffer_size=3, local_steps=2,
+                  local_lr=0.05, batch_size=8, max_staleness=4)
+    D = 4
+
+    def _vec(self, fl=None, **kw):
+        kw.setdefault("rounds_per_launch", 4)
+        return functools.partial(
+            run_vectorized, _quad_loss, {"w": jnp.zeros(self.D)},
+            _quad_clients(d=self.D), fl or self.FL, seed=0,
+            capture_state=True, registry=MetricsRegistry(), **kw)
+
+    def _pop(self, **kw):
+        return functools.partial(
+            run_population, _quad_loss, {"w": jnp.zeros(self.D)},
+            _quad_clients(d=self.D), self.FL, seed=0,
+            scenario=get_scenario("paper-fig1"), rounds_per_launch=4,
+            capture_state=True, registry=MetricsRegistry(), **kw)
+
+    def test_vectorized_records_every_engine_span(self):
+        tr = Tracer(annotate=False)
+        _, events, cover = _timed_call(tr, self._vec(), total_rounds=16)
+        by = _by_name(events)
+        assert set(by) == set(ENGINE_SPANS)
+        assert [len(by[n]) for n in (SPAN_PREPARE, SPAN_HOST_SYNC,
+                                     SPAN_CHECKPOINT)] == [1, 1, 1]
+        for name in (SPAN_COLLECT, SPAN_GATHER, SPAN_STAGE, SPAN_APPLY):
+            assert [ev["args"]["rounds"] for ev in by[name]] == [4] * 4
+        assert cover >= 0.9, cover
+
+    def test_population_records_its_spans(self):
+        tr = Tracer(annotate=False)
+        _, events, cover = _timed_call(tr, self._pop(), total_rounds=16)
+        by = _by_name(events)
+        # no host walk: the windows are chosen on the device
+        assert set(by) == {SPAN_PREPARE, SPAN_APPLY, SPAN_HOST_SYNC,
+                           SPAN_CHECKPOINT}
+        assert len(by[SPAN_APPLY]) == 4 and len(by[SPAN_PREPARE]) == 1
+        assert cover >= 0.9, cover
+
+    def test_engines_record_into_the_default_tracer(self):
+        tr = default_tracer()
+        for fn in (self._vec(), self._pop()):
+            start = len(tr.events)
+            fn(total_rounds=4)
+            names = {ev["name"] for ev in list(tr.events)[start:]}
+            assert {SPAN_PREPARE, SPAN_APPLY, SPAN_HOST_SYNC} <= names
+
+    @pytest.mark.parametrize("k", [2, 5])
+    def test_span_count_follows_launches_not_uploads(self, k):
+        """K (uploads a window) does not move the count; the number of
+        launches does."""
+        fl = FLConfig(num_clients=6, buffer_size=k, local_steps=2,
+                      local_lr=0.05, batch_size=8, max_staleness=4)
+        counts = []
+        for rounds in (8, 16):
+            tr = Tracer(annotate=False)
+            self._vec(fl)(total_rounds=rounds, tracer=tr)
+            counts.append(len(tr.events))
+        launches = (2, 4)
+        # four spans a launch, three a call
+        assert counts == [4 * n + 3 for n in launches]
+
+    def test_stage_bytes_are_the_chunk_arguments(self):
+        tr = Tracer(annotate=False)
+        self._vec()(total_rounds=8, tracer=tr)
+        fl, d, s = self.FL, self.D, 4
+        k, m, b = fl.buffer_size, fl.local_steps, fl.batch_size
+        per_round = 4 * (k                    # base slots, int32
+                         + k * m * b * d      # batch x, f32
+                         + k * m * b          # batch y
+                         + k * b * d          # probe x
+                         + k * b              # probe y
+                         + k + k              # sizes, taus
+                         + 1)                 # the new ring slot
+        stages = [ev for ev in tr.events if ev["name"] == SPAN_STAGE]
+        assert [ev["args"]["bytes"] for ev in stages] == [s * per_round] * 2
+
+    def test_prepare_bytes_count_the_restored_state(self):
+        tr = Tracer(annotate=False)
+        half = self._vec()(total_rounds=4, tracer=tr)
+        self._vec()(total_rounds=8, tracer=tr, init_state=half.final_state)
+        prep = [ev for ev in tr.events if ev["name"] == SPAN_PREPARE]
+        st = half.final_state
+        ring = np.asarray(st.ring).nbytes
+        # fresh: the start params are already on the device
+        assert [ev["args"]["bytes"] for ev in prep] == [
+            0, ring + np.asarray(st.params["w"]).nbytes]
+
+    @pytest.mark.parametrize("engine", ["vectorized", "population"])
+    def test_resume_stays_bit_identical(self, engine):
+        """Recording into the default tracer leaves a resumed run
+        bit-identical to the uninterrupted one."""
+        make = self._vec if engine == "vectorized" else self._pop
+        full = make()(total_rounds=8)
+        half = make()(total_rounds=4)
+        resumed = make()(total_rounds=8, init_state=half.final_state)
+        assert [r["clients"] for r in resumed.round_log] == \
+            [r["clients"] for r in full.round_log]
+        for a, b in zip(resumed.round_log, full.round_log):
+            np.testing.assert_array_equal(np.asarray(a["weights"]),
+                                          np.asarray(b["weights"]))
+        np.testing.assert_array_equal(
+            np.asarray(resumed.final_state.params["w"]),
+            np.asarray(full.final_state.params["w"]))
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ one process may load libtpu at a time, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,13 @@ def whisper_np():
     return n
 
 
+def _kernel_calls(text, kernel):
+    """The custom-call instructions named after ``kernel`` (``<kernel>.<n>``):
+    each main-path kernel's scope names its call, whatever jitted
+    function is around it."""
+    return re.findall(rf"%{kernel}\.\d+ = [^\n]*custom-call\(", text)
+
+
 def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile()
@@ -90,7 +98,9 @@ def test_fused_server_kernel_compiles(one_chip, whisper_np, k, case):
         lambda x, b, d, p, t, m: wk.fused_server_pallas(x, b, d, p, t, m),
         one_chip, ((n,), f32), ((k, n), f32), ((k, n), f32), ((k,), f32),
         ((k,), f32), ((k,), f32))
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(_kernel_calls(text, "fused_server_pallas")) == 1
 
 
 @pytest.mark.parametrize("kernel", ["sq_dists", "weighted_sum"])
@@ -104,7 +114,9 @@ def test_two_phase_kernels_compile(one_chip, whisper_np, kernel, k, case):
     else:
         compiled = _compile(wk.weighted_sum_pallas, one_chip, ((k, n), f32),
                             ((k,), f32))
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(_kernel_calls(text, f"{kernel}_pallas")) == 1
 
 
 @pytest.mark.parametrize("k,case", [(16, "whisper"), (64, "small")])
@@ -115,7 +127,9 @@ def test_int8_distance_kernel_compiles(one_chip, whisper_np, k, case):
         lambda x, c, s, z: int8_sq_dists_pallas(x, c, s, z, qblock=qblock),
         one_chip, ((n,), f32), ((k, n), jnp.int8), ((k, n // qblock), f32),
         ((k, n // qblock), f32))
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(_kernel_calls(text, "int8_sq_dists_pallas")) == 1
 
 
 def test_fused_server_pass_fits_one_chip(one_chip, whisper_np):
@@ -127,6 +141,8 @@ def test_fused_server_pass_fits_one_chip(one_chip, whisper_np):
                                                     mode="fused"),
         one_chip, ((n,), f32), ((k, n), f32), ((k, n), f32), ((k,), f32),
         ((k,), f32), ((k,), f32))
+    # inside the round's own server_pass scope the kernel keeps its name
+    assert len(_kernel_calls(compiled.as_text(), "fused_server_pallas")) == 1
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
@@ -152,6 +168,8 @@ def test_sharded_server_pass_compiles_on_four_chips(topo, whisper_np):
         *args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    assert _kernel_calls(text, "sq_dists_pallas")
+    assert _kernel_calls(text, "weighted_sum_pallas")
     mem = compiled.memory_analysis()
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes)
