@@ -1,6 +1,15 @@
 """LeNet-5 (the paper's Fashion-MNIST backbone), pure JAX.
 
 Conv(6,5x5) -> avgpool -> Conv(16,5x5) -> avgpool -> FC120 -> FC84 -> FC10.
+
+The first convolution is computed as patches times a matrix. It reads the
+input images, which need no gradient, so its weight gradient is one dot
+over the patches; vmapped over clients' weights that dot gains a batch
+dimension. As a convolution, the same vmap folds the clients into a
+feature-grouped convolution whose weight gradient has one input channel
+per group, which a TPU runs as an elementwise multiply-add. The second
+convolution stays one: as patches, its input gradient would need the
+patches' transpose, a scatter-add of kh*kw slices.
 """
 from __future__ import annotations
 
@@ -34,6 +43,29 @@ def _conv(x, w, b):
     return y + b
 
 
+def _patch_conv(x, w, b):
+    """VALID, stride-1 convolution (NHWC x HWIO) as im2col patches @ w.
+
+    The patches are laid out (kh*kw, h, wd*B*cin): each is a slice of the
+    images transposed to (H, W*B*cin), so its minor axis stays dense on a
+    TPU. With (kh*kw*cin) or the batch as the minor axis, the patches pad
+    to 128 lanes, and LeNet's round runs slower than with the convolution.
+    """
+    kh, kw, cin, cout = w.shape
+    n, hi, wi, _ = x.shape
+    h, wd = hi - kh + 1, wi - kw + 1
+    c = n * cin
+    rows = jnp.transpose(x, (1, 2, 0, 3)).reshape(hi, wi * c)
+    patches = jnp.stack([rows[i:i + h, j * c:(j + wd) * c]
+                         for i in range(kh) for j in range(kw)])
+    patches = patches.reshape(kh * kw, h, wd * n, cin)
+    y = jnp.einsum("kpqi,kio->pqo", patches, w.reshape(kh * kw, cin, cout))
+    # The bias goes after the transpose: added before it, XLA lays out the
+    # first pool's gradient with the images minor and splits it into two
+    # one-axis reduce-windows as slow as the grouped convolution.
+    return jnp.transpose(y.reshape(h, wd, n, cout), (2, 0, 1, 3)) + b
+
+
 def _avgpool2(x):
     return jax.lax.reduce_window(x, 0.0, jax.lax.add, (1, 2, 2, 1),
                                  (1, 2, 2, 1), "VALID") / 4.0
@@ -41,7 +73,7 @@ def _avgpool2(x):
 
 def apply_lenet(params, x):
     """x: (B, 28, 28, 1) -> logits (B, 10)."""
-    h = jnp.tanh(_conv(x, params["c1"]["w"], params["c1"]["b"]))  # (B,24,24,6)
+    h = jnp.tanh(_patch_conv(x, params["c1"]["w"], params["c1"]["b"]))  # (B,24,24,6)
     h = _avgpool2(h)  # (B,12,12,6)
     h = jnp.tanh(_conv(h, params["c2"]["w"], params["c2"]["b"]))  # (B,8,8,16)
     h = _avgpool2(h)  # (B,4,4,16)
