@@ -16,6 +16,19 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope extension (Peng et al. 2023, arXiv:2309.00071), with the
+    key names of DeepSeek-V2's ``rope_scaling``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | hybrid | ssm | vlm | audio
@@ -40,6 +53,12 @@ class ModelConfig:
     # logits tensor is never materialised (head matmul fused per chunk)
     remat_block: int = 0  # perf: sqrt-remat — checkpoint every Nth layer
     # boundary instead of every layer (L/N saved carries + N transient)
+    rope_yarn: Optional[YarnRope] = None  # YaRN-extended rope (MLA)
+    # --- latent attention (MLA, DeepSeek-V2); kv_lora_rank 0 = no MLA ---
+    kv_lora_rank: int = 0  # width of the compressed KV latent c_kv
+    qk_nope_head_dim: int = 0  # per-head query/key part without rope
+    qk_rope_head_dim: int = 0  # rope part; the key's is shared by heads
+    v_head_dim: int = 0
     # --- MoE ---
     num_experts: int = 0
     experts_per_token: int = 0
@@ -48,6 +67,12 @@ class ModelConfig:
     dense_residual_ff: bool = False  # arctic: dense FFN in parallel with MoE
     router_aux_coef: float = 0.01
     moe_capacity_factor: float = 1.25
+    first_k_dense: int = 0  # leading dense layers (width d_ff) in front
+    # of the scanned MoE stack; num_layers counts them
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count): the
+    # dropless expert layer that holds only these experts of the
+    # num_experts it routes over (models/moe.py::moe_held_ffn); None =
+    # the capacity-dispatch layer holding every expert
     moe_groups: int = 1  # dispatch groups: sort/scatter stay LOCAL to each
     # group (group = data shard) so SPMD never re-replicates the token
     # tensor — see EXPERIMENTS.md §Perf (arctic iteration 2)
@@ -66,6 +91,8 @@ class ModelConfig:
     # --- numerics ---
     param_dtype: str = "float32"
     compute_dtype: str = "float32"
+    matmul_dtype: Optional[str] = None  # operands of the MLA, held-expert,
+    # dense-FFN and head matmuls cast to it, accumulated in float32
 
     @property
     def resolved_head_dim(self) -> int:
@@ -100,6 +127,11 @@ class ModelConfig:
     @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff if self.moe_d_ff else self.d_ff
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts this layer's parameters hold."""
+        return self.experts_held or (0, self.num_experts)
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -205,6 +237,7 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         vocab_size=min(cfg.vocab_size, 512),
         param_dtype="float32",
         compute_dtype="float32",
+        matmul_dtype=None,
     )
     if cfg.is_moe:
         kw.update(
@@ -220,4 +253,11 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         kw.update(num_patches=min(cfg.num_patches, 16))
     if cfg.attn_window:
         kw.update(attn_window=min(cfg.attn_window, 32))
+    if cfg.first_k_dense:
+        kw.update(first_k_dense=1)  # one dense layer, then one MoE layer
+    if cfg.experts_held:
+        kw.update(experts_held=(0, kw["num_experts"]))
+    if cfg.kv_lora_rank:
+        kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                  v_head_dim=16)
     return cfg.replace(**kw)

@@ -1,5 +1,6 @@
-"""DeepSeekMoE-16B — fine-grained MoE: 2 shared + 64 routed experts, top-6,
-per-expert d_ff=1408. [arXiv:2401.06066]"""
+"""DeepSeekMoE-16B — fine-grained MoE: a leading dense layer (d_ff=10944),
+then 27 layers of 2 shared + 64 routed experts, top-6, per-expert
+d_ff=1408. [arXiv:2401.06066]"""
 from repro.configs.base import ArchConfig, ModelConfig
 
 CONFIG = ArchConfig(
@@ -7,10 +8,11 @@ CONFIG = ArchConfig(
         name="deepseek-moe-16b",
         family="moe",
         num_layers=28,
+        first_k_dense=1,
         d_model=2048,
         num_heads=16,
         num_kv_heads=16,
-        d_ff=1408,
+        d_ff=10944,  # the dense layer's width
         vocab_size=102400,
         activation="swiglu",
         num_experts=64,
@@ -22,8 +24,6 @@ CONFIG = ArchConfig(
     ),
     source="[arXiv:2401.06066]",
     notes="Fine-grained expert segmentation; shared experts always active. "
-          "Deviation: the released model's first layer is dense — we use "
-          "MoE in all layers for scan-over-layers homogeneity (protocol- "
-          "irrelevant; recorded).",
+          "The first layer is dense, as released.",
     long_context_window=4096,
 )

@@ -17,6 +17,7 @@ _MODULES: Dict[str, str] = {
     "pixtral-12b": "repro.configs.pixtral_12b",
     "gemma-7b": "repro.configs.gemma_7b",
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "qwen3-1.7b": "repro.configs.qwen3_1p7b",
     "falcon-mamba-7b": "repro.configs.falcon_mamba_7b",
     "whisper-tiny": "repro.configs.whisper_tiny",

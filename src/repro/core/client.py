@@ -5,8 +5,10 @@
     (base_params, batches, key) -> (delta, metrics)
 
 where ``delta = base - end`` is the *cumulative update* Delta_i of the paper
-(sum over local steps of lr * grad, for plain SGD), and ``batches`` is a
-pytree whose leaves carry a leading (M, ...) local-step axis.
+(sum over local steps of lr * grad, for plain SGD), ``batches`` is a
+pytree whose leaves carry a leading (M, ...) local-step axis, and
+``metrics`` are the loss's own metrics of each local step, stacked on that
+axis.
 
 ``make_fresh_loss_fn`` evaluates the CURRENT global model on a fresh local
 mini-batch — the P_i^t probe of eq. (4). In the real protocol the server
@@ -35,7 +37,7 @@ def make_local_update_fn(loss_fn: Callable, local_steps: int, local_lr: float,
     related-work line cites; composes with any aggregation policy.
     """
     opt = sgd(local_lr, momentum=momentum)
-    grad_fn = jax.grad(lambda p, b: loss_fn(p, b)[0])
+    grad_fn = jax.grad(loss_fn, has_aux=True)
 
     @jax.named_scope("local_update")
     def local_update(base_params, batches, _key=None):
@@ -43,18 +45,18 @@ def make_local_update_fn(loss_fn: Callable, local_steps: int, local_lr: float,
 
         def step(carry, batch):
             params, ostate = carry
-            g = grad_fn(params, batch)
+            g, metrics = grad_fn(params, batch)
             if prox_mu:
                 g = jax.tree.map(
                     lambda gi, p, b: gi + prox_mu * (p - b).astype(gi.dtype),
                     g, params, base_params)
             upd, ostate = opt.update(g, ostate, params)
-            return (apply_updates(params, upd), ostate), None
+            return (apply_updates(params, upd), ostate), metrics
 
-        (end_params, _), _ = jax.lax.scan(step, (base_params, opt_state),
-                                          batches, length=local_steps)
+        (end_params, _), metrics = jax.lax.scan(
+            step, (base_params, opt_state), batches, length=local_steps)
         delta = tree_sub(base_params, end_params)  # Delta_i (gradient-like)
-        return delta, {}
+        return delta, metrics
 
     return local_update
 

@@ -27,17 +27,29 @@ Two deployment mappings (DESIGN.md §2.1):
   reference pinned to the current model; staleness distances use the
   scalar update-norm ring (cross terms dropped; exact variant =
   simulator; parity tested on small models in tests/test_round_body.py).
-  This module only keeps the buffer state machine.
+  This module only keeps the buffer state machine, and the host loop that
+  folds one upload after another through it (``fold_upload``).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple
+from typing import Any, Callable, Dict, NamedTuple, Optional
+
+import heapq
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import FLConfig
 from repro.core.round_body import make_round_body, make_streaming_round_body
+from repro.obs.metrics import MetricsRegistry, default_registry
+from repro.obs.trace import (
+    SPAN_CONTRIBUTE,
+    SPAN_HOST_SYNC,
+    SPAN_STAGE,
+    Tracer,
+    default_tracer,
+)
 from repro.utils.pytree import tree_sq_dist
 
 
@@ -175,12 +187,13 @@ def make_dist_step(loss_fn: Callable, fl: FLConfig) -> Callable:
 
     Metrics: ``buffered`` is the PRE-apply fill count (so the round that
     triggers the apply reports K, not 0) and ``applied`` is a {0,1} flag
-    for whether this step flushed the buffer.
+    for whether this step flushed the buffer; ``local`` holds the loss's
+    metrics of the M local steps, each stacked on a leading (M,) axis.
     """
     streaming = make_streaming_round_body(loss_fn, fl)
 
     def step(state: DistFLState, batch: Dict[str, Any]):
-        accum, v, fresh = streaming.contribute(
+        accum, v, info = streaming.contribute(
             state.global_params, state.accum, state.update_norm_ring,
             batch["local"], batch["probe"],
             batch["data_size"].astype(jnp.float32), batch["tau"])
@@ -205,10 +218,77 @@ def make_dist_step(loss_fn: Callable, fl: FLConfig) -> Callable:
         new_params, accum, v_buf, count, version, ring = jax.lax.cond(
             applied, apply_fn, hold_fn, (accum, v_buf, count))
 
-        metrics = {"fresh_loss": fresh, "v_weight": v,
+        metrics = {"fresh_loss": info["fresh_loss"], "v_weight": v,
                    "buffered": state.count + 1,
-                   "applied": applied.astype(jnp.int32)}
+                   "applied": applied.astype(jnp.int32),
+                   "local": info["local"]}
         return DistFLState(new_params, accum, v_buf, count, version,
                            ring), metrics
 
     return step
+
+
+def fold_upload(step: Callable, state: DistFLState, batch: Dict[str, Any], *,
+                tracer: Optional[Tracer] = None,
+                registry: Optional[MetricsRegistry] = None):
+    """Fold one client upload through the jitted ``make_dist_step``.
+
+    The upload loop's one step, on the host: stage the upload's batch on
+    the device (span ``stage``, arg ``bytes``), dispatch the step (span
+    ``contribute``), then fetch its weight and fresh loss as the server's
+    acknowledgement (span ``host_sync``, which waits for the device). The
+    fetch also carries the loss's own metrics of the M local steps, each
+    recorded as an arg of that span under its name. Counters
+    ``dist_uploads_total`` and ``dist_applies_total``. Returns the new
+    state and the acknowledgement (host values).
+    """
+    tr = tracer if tracer is not None else default_tracer()
+    reg = registry if registry is not None else default_registry()
+    with tr.span(SPAN_STAGE) as sp:
+        staged = jax.device_put(batch)
+        sp.set(bytes=int(sum(np.asarray(a).nbytes
+                             for a in jax.tree.leaves(batch))))
+    with tr.span(SPAN_CONTRIBUTE):
+        state, mets = step(state, staged)
+    reg.counter("dist_uploads_total").inc()
+    with tr.span(SPAN_HOST_SYNC, what="ack") as sp:
+        ack = jax.device_get(mets)
+        sp.set(**{name: np.asarray(value).tolist()
+                  for name, value in ack["local"].items()})
+    if ack["applied"]:
+        reg.counter("dist_applies_total").inc()
+    return state, ack
+
+
+def staleness_stream(behavior: Any, k: int, n: int):
+    """Client ids and staleness tau of the first ``n`` uploads that buffered
+    async FL with buffer K sees over a client population.
+
+    ``behavior`` is a ``sim.scenarios.ClientBehavior``: each client trains
+    from the version it pulled, for the durations it draws; an upload's
+    tau is the server's version when it lands minus that; every K landed
+    uploads advance the version; the uploading client then pulls the
+    current version and trains again. Lost uploads are skipped. What the
+    distributed-client mapping, which trains every upload from the
+    current model, takes as each upload's simulated tau.
+    """
+    heap = []
+    for c in range(behavior.num_clients):
+        start = behavior.next_start(c, 0.0)
+        heapq.heappush(heap, (start + behavior.duration(c, start), c))
+    base = [0] * behavior.num_clients
+    version, buffered = 0, 0
+    cids, taus = [], []
+    while len(taus) < n:
+        t, c = heapq.heappop(heap)
+        _, lost = behavior.next_upload(c)
+        if not lost:
+            cids.append(c)
+            taus.append(version - base[c])
+            buffered += 1
+            if buffered == k:
+                version, buffered = version + 1, 0
+        base[c] = version
+        start = behavior.next_start(c, t)
+        heapq.heappush(heap, (start + behavior.duration(c, start), c))
+    return np.asarray(cids, np.int64), np.asarray(taus, np.int32)

@@ -296,7 +296,9 @@ def make_streaming_round_body(loss_fn: Callable,
     ``k_eff`` alone.
 
     ``contribute(params, accum, update_norm_ring, batch, probe,
-    data_size, tau) -> (new_accum, v, fresh)`` and
+    data_size, tau) -> (new_accum, v, info)``, ``info`` the upload's
+    ``fresh_loss`` (eq. 4) and under ``local`` the loss metrics of its M
+    local steps (``make_local_update_fn``), and
     ``apply(params, accum, v_buf, count, update_norm_ring) ->
     (new_params, new_ring)``.
     """
@@ -307,7 +309,7 @@ def make_streaming_round_body(loss_fn: Callable,
 
     def contribute(params, accum, update_norm_ring, batch, probe, data_size,
                    tau):
-        delta, _ = local_update(params, batch)
+        delta, local = local_update(params, batch)
 
         # eq. 4 probe of the CURRENT model
         with jax.named_scope("probe"):
@@ -329,11 +331,13 @@ def make_streaming_round_body(loss_fn: Callable,
                                  s_min=fl.s_min, poly_a=fl.poly_a,
                                  hinge_a=fl.hinge_a, hinge_b=fl.hinge_b,
                                  normalize="none")[0]
-        new_accum = jax.tree.map(
-            lambda a, dl: a + (v * dl.astype(jnp.float32)).astype(a.dtype),
-            accum, delta)
-        return new_accum, v, fresh
+        with jax.named_scope("fold"):
+            new_accum = jax.tree.map(
+                lambda a, dl: a + (v * dl.astype(jnp.float32)).astype(a.dtype),
+                accum, delta)
+        return new_accum, v, {"fresh_loss": fresh, "local": local}
 
+    @jax.named_scope("fold")
     def apply(params, accum, v_buf, count, update_norm_ring):
         # eq. 5 on the running accumulator: x - eta_g/k_eff * sum w_i D_i
         # with w_i = v_i * k_eff / sum v_j ("mean") or w_i = v_i ("none")

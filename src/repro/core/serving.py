@@ -295,9 +295,9 @@ class ServingController:
 
         def contribute_step(params, accum, ring, v_buf, count, batch, probe,
                             size, tau):
-            accum, v, fresh = body.contribute(params, accum, ring, batch,
-                                              probe, size, tau)
-            return accum, v_buf.at[count].set(v), v, fresh
+            accum, v, info = body.contribute(params, accum, ring, batch,
+                                             probe, size, tau)
+            return accum, v_buf.at[count].set(v), v, info["fresh_loss"]
 
         self._contribute = jax.jit(contribute_step)
         self._apply = jax.jit(body.apply)

@@ -186,8 +186,7 @@ def _build_dist_train(model, fl, shape, mesh, baxes, dp, meta) -> Program:
         "tau": P(), "data_size": P(),
     }
     step = make_dist_step(model.loss, fl)
-    metrics_specs = {"fresh_loss": P(), "v_weight": P(), "buffered": P(),
-                     "applied": P()}
+    metrics_specs = P()  # every metric replicated (a prefix of the tree)
     meta.update(cohort=1, local_batch=shape.global_batch, local_steps=m)
     return Program(
         name=f"{meta['arch']}:{meta['shape']}", kind="train", step_fn=step,

@@ -7,6 +7,10 @@ Runs REAL steps (allocates params), so it is meant for:
 The arrival schedule (which cohort slots' uploads are buffered each round)
 comes from the same heterogeneous latency model as the event-driven
 simulator, so compiled training reproduces realistic staleness patterns.
+Archs with ``fl_mode == "distributed"`` run the distributed-client mapping
+instead: one client spanning the mesh folds its uploads one at a time
+(``core/cohort.py::fold_upload``), each with the staleness the same
+latency model gives it, and every K-th upload applies eq. 5.
 
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
@@ -25,8 +29,16 @@ import numpy as np
 from repro.configs.base import INPUT_SHAPES, FLConfig
 from repro.configs.registry import get_arch
 from repro.configs.base import smoke_variant
-from repro.core.cohort import init_cohort_state, make_cohort_step
+from repro.core.cohort import (
+    fold_upload,
+    init_cohort_state,
+    init_dist_state,
+    make_cohort_step,
+    make_dist_step,
+    staleness_stream,
+)
 from repro.core.simulator import LatencyModel
+from repro.sim.scenarios import ClientBehavior
 from repro.data.synthetic import make_lm_token_stream
 from repro.launch.cli import (
     ObsStack,
@@ -84,6 +96,37 @@ def make_batches(cfg, cohort, m, b, bp, seq, rng):
     return batch
 
 
+def run_distributed(model, fl, *, clients: int, rounds: int, seq: int,
+                    batch: int, seed: int, log, obs) -> int:
+    """``rounds`` server rounds of the distributed-client mapping: K
+    uploads each, folded one after another, each acknowledged before the
+    next. Returns the global version reached."""
+    cfg = model.cfg
+    rng = np.random.default_rng(seed)
+    behavior = ClientBehavior.from_latency(
+        LatencyModel.heterogeneous(clients, seed=seed), clients, seed)
+    cids, taus = staleness_stream(behavior, fl.buffer_size,
+                                  rounds * fl.buffer_size)
+    sizes = rng.integers(500, 2000, clients).astype(np.float32)
+    state = init_dist_state(model.init(jax.random.PRNGKey(seed)), fl)
+    step = jax.jit(make_dist_step(model.loss, fl), donate_argnums=0)
+    tracer = obs.tracer if obs.trace_out else None
+    for i, (cid, tau) in enumerate(zip(cids, taus)):
+        upload = jax.tree.map(
+            lambda a: a[0],
+            make_batches(cfg, 1, fl.local_steps, batch, 1, seq, rng))
+        upload.update(tau=np.int32(tau), data_size=sizes[cid])
+        t0 = time.time()
+        state, ack = fold_upload(step, state, upload, tracer=tracer,
+                                 registry=obs.registry)
+        log(f"upload {i + 1}: client={cid} tau={tau} "
+            f"fresh_loss={ack['fresh_loss']:.4f} v={ack['v_weight']:.4g} "
+            f"applied={int(ack['applied'])} ({time.time() - t0:.1f}s)")
+        if ack["applied"]:
+            obs.round_hook((i + 1) // fl.buffer_size)
+    return int(state.version)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -129,21 +172,29 @@ def main() -> None:
                   weighting=args.weighting, ring_codec=args.ring_codec)
     model = build_model(cfg)
     mesh = make_host_mesh()
-    latency = LatencyModel.heterogeneous(cohort, seed=args.seed)
-    sched = arrival_schedule(cohort, args.buffer_k, latency, args.rounds,
-                             seed=args.seed)
-
-    rng = np.random.default_rng(args.seed)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    state = init_cohort_state(params, cohort)
-    step = jax.jit(make_cohort_step(model.loss, fl), donate_argnums=0)
-    sizes = jnp.asarray(rng.integers(500, 2000, cohort), jnp.float32)
 
     from repro.launch.program import make_io_hooks
     log, eval_metrics, maybe_save = make_io_hooks(
         ckpt_path=args.ckpt, ckpt_every=args.ckpt_every,
         log_fn=logging.getLogger("repro.launch.train").info,
         registry=obs.registry, tracer=obs.tracer, sink=obs.sink)
+    if arch.fl_mode == "distributed":
+        with mesh:
+            version = run_distributed(model, fl, clients=cohort,
+                                      rounds=args.rounds, seq=seq, batch=b,
+                                      seed=args.seed, log=log, obs=obs)
+        log(f"done; global version = {version}")
+        obs.finish(args.rounds)
+        return
+
+    latency = LatencyModel.heterogeneous(cohort, seed=args.seed)
+    sched = arrival_schedule(cohort, args.buffer_k, latency, args.rounds,
+                             seed=args.seed)
+    rng = np.random.default_rng(args.seed)
+    params = model.init(jax.random.PRNGKey(args.seed))
+    state = init_cohort_state(params, cohort)
+    step = jax.jit(make_cohort_step(model.loss, fl), donate_argnums=0)
+    sizes = jnp.asarray(rng.integers(500, 2000, cohort), jnp.float32)
 
     with mesh:
         for r in range(args.rounds):

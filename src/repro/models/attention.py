@@ -1,6 +1,8 @@
 """Attention: GQA/MQA, qk-norm, optional bias, RoPE, causal / bidirectional /
 sliding-window, chunked (flash-style, O(S) memory) training path, and
-single-token decode against full or ring KV caches.
+single-token decode against full or ring KV caches. Latent attention
+(MLA, DeepSeek-V2: low-rank KV, a rope key shared across heads, YaRN)
+when ``cfg.kv_lora_rank`` is set.
 
 The chunked path is pure JAX (lax.scan + online softmax) so it lowers on any
 backend — it is the XLA fallback of the Pallas flash kernel in
@@ -14,7 +16,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init, rms_norm
+from repro.models.layers import (
+    apply_rope,
+    dense_init,
+    matmul,
+    rms_norm,
+    rope_frequencies,
+    yarn_frequencies,
+    yarn_mscale,
+)
 
 NEG_INF = -1e30
 
@@ -25,6 +35,8 @@ NEG_INF = -1e30
 
 
 def init_attention(key, cfg, cross: bool = False):
+    if cfg.kv_lora_rank:
+        return init_mla(key, cfg)
     dtype = jnp.dtype(cfg.param_dtype)
     d = cfg.d_model
     hd = cfg.resolved_head_dim
@@ -83,9 +95,9 @@ def _repeat_kv(x, n_rep: int):
 
 
 def _full_attention(q, k, v, causal: bool, q_offset: int = 0,
-                    window: int = 0):
-    """Materialised-scores attention. q:(B,Sq,H,D) k,v:(B,Sk,H,D)."""
-    scale = q.shape[-1] ** -0.5
+                    window: int = 0, scale: Optional[float] = None):
+    """Materialised-scores attention. q,k:(B,S,H,D) v:(B,Sk,H,Dv)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
                         k.astype(jnp.float32))
     if causal:
@@ -100,14 +112,19 @@ def _full_attention(q, k, v, causal: bool, q_offset: int = 0,
     return out
 
 
-def _chunked_causal_attention(q, k, v, q_chunk: int):
+def _chunked_causal_attention(q, k, v, q_chunk: int,
+                              scale: Optional[float] = None):
     """Flash-style online-softmax over q chunks; kv masked per chunk.
 
     O(S * q_chunk) live memory. Scans q chunks; each chunk attends to the
     full (masked) key range — the upper-triangle overcount is accepted and
-    accounted for in the roofline notes.
+    accounted for in the roofline notes. The chunk body is checkpointed:
+    the backward pass recomputes each chunk's scores instead of keeping
+    them.
     """
     b, s, h, d = q.shape
+    dv = v.shape[-1]
+    scale = d ** -0.5 if scale is None else scale
     nq = s // q_chunk
     qs = q.reshape(b, nq, q_chunk, h, d).transpose(1, 0, 2, 3, 4)  # (nq,B,c,H,D)
     kpos = jnp.arange(k.shape[1])
@@ -115,7 +132,6 @@ def _chunked_causal_attention(q, k, v, q_chunk: int):
     def body(carry, inp):
         qc, i = inp
         qpos = i * q_chunk + jnp.arange(q_chunk)
-        scale = d ** -0.5
         scores = jnp.einsum("bqhd,bkhd->bhqk", qc.astype(jnp.float32) * scale,
                             k.astype(jnp.float32))
         mask = qpos[:, None] >= kpos[None, :]
@@ -125,7 +141,7 @@ def _chunked_causal_attention(q, k, v, q_chunk: int):
         return carry, out
 
     _, outs = jax.lax.scan(jax.checkpoint(body), 0, (qs, jnp.arange(nq)))
-    return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
+    return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, dv)
 
 
 def _sliding_window_attention(q, k, v, window: int, q_chunk: int):
@@ -169,6 +185,8 @@ def attention_train(cfg, p, x, kv_x=None, causal: bool = True,
                     positions: Optional[jnp.ndarray] = None,
                     window: Optional[int] = None):
     """Self (or cross, via kv_x) attention over a full sequence."""
+    if cfg.kv_lora_rank:
+        return mla_train(cfg, p, x, positions)
     b, s, _ = x.shape
     q = _project_q(cfg, p, x)
     k, v = _project_kv(cfg, p, kv_x if kv_x is not None else x)
@@ -194,12 +212,120 @@ def attention_train(cfg, p, x, kv_x=None, causal: bool = True,
 
 
 # ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2)
+# ---------------------------------------------------------------------------
+# q = x Wq, per head [q_nope | q_pe]; [c_kv | k_pe] = x Wkv_a with c_kv
+# RMS-normed; [k_nope | v] = c_kv Wkv_b per head; rope on q_pe and on the
+# one k_pe that every head shares; k = [k_nope | k_pe]; causal softmax;
+# out Wo. There is no q_lora path (DeepSeek-V2-Lite has none).
+
+
+def init_mla(key, cfg):
+    dtype = jnp.dtype(cfg.param_dtype)
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(kq, d, h * (dn + dr), dtype),
+        "wkv_a": dense_init(ka, d, r + dr, dtype),
+        "kv_norm": jnp.zeros((r,), jnp.float32),
+        "wkv_b": dense_init(kb, r, h * (dn + dv), dtype),
+        "wo": dense_init(ko, h * dv, d, dtype, scale=(h * dv) ** -0.5),
+    }
+
+
+def mla_rope(cfg):
+    """MLA's rope: (inverse frequencies, cos/sin factor), YaRN when
+    ``cfg.rope_yarn`` is set."""
+    dr, y = cfg.qk_rope_head_dim, cfg.rope_yarn
+    if y is None:
+        return rope_frequencies(dr, cfg.rope_theta), 1.0
+    return (jnp.asarray(yarn_frequencies(dr, cfg.rope_theta, y)),
+            yarn_mscale(y.factor, y.mscale)
+            / yarn_mscale(y.factor, y.mscale_all_dim))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(q/k head dim)^-1/2, times YaRN's mscale(factor, mscale_all_dim)^2."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _deinterleave(x):
+    """Rope pairs (x0, x1), (x2, x3), ... to (x0, x2, ..., x1, x3, ...):
+    DeepSeek-V2 permutes its rope dims so before the rotate-half rope."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _mla_qkv(cfg, p, x, positions):
+    """q, k: (B, S, H, dn + dr); v: (B, S, H, dv)."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = matmul(cfg, x, p["wq"]).reshape(b, s, h, dn + dr)
+    kv_a = matmul(cfg, x, p["wkv_a"])
+    c_kv = rms_norm(kv_a[..., :r], p["kv_norm"])
+    kv = matmul(cfg, c_kv, p["wkv_b"]).reshape(b, s, h, dn + dv)
+    freqs, cs = mla_rope(cfg)
+    q_pe = apply_rope(_deinterleave(q[..., dn:]), positions, cfg.rope_theta,
+                      freqs, cs)
+    k_pe = apply_rope(_deinterleave(kv_a[:, :, None, r:]), positions,
+                      cfg.rope_theta, freqs, cs)  # (B, S, 1, dr)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_pe, (b, s, h, dr))], axis=-1)
+    return q, k, kv[..., dn:]
+
+
+@jax.named_scope("mla")
+def mla_train(cfg, p, x, positions=None):
+    b, s, _ = x.shape
+    if positions is None:
+        positions = jnp.arange(s)[None, :]
+    q, k, v = _mla_qkv(cfg, p, x, positions)
+    scale = mla_softmax_scale(cfg)
+    if s > FULL_ATTN_MAX_SEQ or (cfg.force_chunked_attn and s > Q_CHUNK):
+        out = _chunked_causal_attention(q, k, v, Q_CHUNK, scale=scale)
+    else:
+        out = _full_attention(q, k, v, causal=True, scale=scale)
+    return matmul(cfg, out.reshape(b, s, -1), p["wo"])
+
+
+@jax.named_scope("mla")
+def mla_decode(cfg, p, x, cache, pos):
+    """One token against a cache of the expanded per-head k and v (no
+    latent cache is kept)."""
+    b = x.shape[0]
+    q, k_new, v_new = _mla_qkv(cfg, p, x, jnp.full((b, 1), pos))
+    k = jax.lax.dynamic_update_slice_in_dim(
+        cache["k"], k_new.astype(cache["k"].dtype), pos, axis=1)
+    v = jax.lax.dynamic_update_slice_in_dim(
+        cache["v"], v_new.astype(cache["v"].dtype), pos, axis=1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk",
+                        q.astype(jnp.float32) * mla_softmax_scale(cfg),
+                        k.astype(jnp.float32))
+    valid = jnp.arange(k.shape[1]) <= pos
+    scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+    return matmul(cfg, out.reshape(b, 1, -1), p["wo"]), {"k": k, "v": v}
+
+
+# ---------------------------------------------------------------------------
 # decode (single new token against a KV cache)
 # ---------------------------------------------------------------------------
 
 
 def init_kv_cache(cfg, batch: int, cache_len: int, dtype):
     """Full cache, or ring cache of size ``attn_window`` when SWA."""
+    if cfg.kv_lora_rank:
+        h = cfg.num_heads
+        dk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return {"k": jnp.zeros((batch, cache_len, h, dk), dtype),
+                "v": jnp.zeros((batch, cache_len, h, cfg.v_head_dim), dtype)}
     hd = cfg.resolved_head_dim
     length = min(cache_len, cfg.attn_window) if cfg.attn_window else cache_len
     shape = (batch, length, cfg.num_kv_heads, hd)
@@ -212,6 +338,8 @@ def attention_decode(cfg, p, x, cache, pos):
     Cache semantics: full cache writes at index ``pos``; ring (SWA) cache
     writes at ``pos % window`` and masks by recency.
     """
+    if cfg.kv_lora_rank:
+        return mla_decode(cfg, p, x, cache, pos)
     b = x.shape[0]
     q = _project_q(cfg, p, x)  # (B,1,H,Dh)
     k_new, v_new = _project_kv(cfg, p, x)  # (B,1,Hkv,Dh)

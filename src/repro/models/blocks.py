@@ -3,6 +3,9 @@
 Each architecture family maps to one homogeneous block type so the whole
 stack is a single ``lax.scan`` over layer-stacked parameters — compact HLO
 at any depth (80-layer qwen1.5-110b lowers as one loop), remat-friendly.
+Leading dense layers of an MoE model (``cfg.first_k_dense``) are a second,
+dense stack scanned in front of it (``models/model.py``); a layer's FFN
+kind follows its parameters (``"moe"`` or ``"mlp"``).
 """
 from __future__ import annotations
 
@@ -22,8 +25,9 @@ from repro.models.layers import apply_mlp, apply_norm, init_mlp, make_norm_param
 # ---------------------------------------------------------------------------
 
 
-def init_layer(key, cfg, cross: bool = False):
-    """One decoder layer's params for the cfg's family."""
+def init_layer(key, cfg, cross: bool = False, dense: bool = False):
+    """One decoder layer's params for the cfg's family; ``dense`` gives an
+    MoE model's leading dense layer (an MLP of width ``d_ff``)."""
     ks = jax.random.split(key, 8)
     p = {}
     if cfg.is_ssm_only:
@@ -40,7 +44,7 @@ def init_layer(key, cfg, cross: bool = False):
         p["norm_x"] = make_norm_params(cfg, cfg.d_model)
         p["xattn"] = attn.init_attention(ks[2], cfg, cross=True)
     p["norm2"] = make_norm_params(cfg, cfg.d_model)
-    if cfg.is_moe:
+    if cfg.is_moe and not dense:
         p["moe"] = moe_mod.init_moe(ks[3], cfg)
         if cfg.dense_residual_ff:
             p["mlp"] = init_mlp(ks[4], cfg)
@@ -49,9 +53,11 @@ def init_layer(key, cfg, cross: bool = False):
     return p
 
 
-def init_stack(key, cfg, num_layers: int, cross: bool = False):
+def init_stack(key, cfg, num_layers: int, cross: bool = False,
+               dense: bool = False):
     keys = jax.random.split(key, num_layers)
-    return jax.vmap(lambda k: init_layer(k, cfg, cross=cross))(keys)
+    return jax.vmap(lambda k: init_layer(k, cfg, cross=cross,
+                                         dense=dense))(keys)
 
 
 # ---------------------------------------------------------------------------
@@ -74,20 +80,27 @@ def _mixer_train(cfg, p, x, window):
 
 
 def _ffn_train(cfg, p, x):
+    """Returns (x, aux_loss, stats): ``stats`` holds the held experts'
+    routed-pair counts (``expert_counts``) where the layer has them."""
+    zero = jnp.zeros((), jnp.float32)
     if cfg.is_ssm_only:
-        return x, jnp.zeros((), jnp.float32)  # mamba block subsumes the MLP
+        return x, zero, {}  # mamba block subsumes the MLP
     h = apply_norm(cfg, p["norm2"], x)
-    if cfg.is_moe:
+    if "moe" in p and cfg.experts_held:
+        y, aux, counts = moe_mod.moe_held_ffn(cfg, p["moe"], h)
+        return x + y, aux, {"expert_counts": counts}
+    if "moe" in p:
         y, aux = moe_mod.moe_ffn(cfg, p["moe"], h)
         if cfg.dense_residual_ff:
             y = y + apply_mlp(cfg, p["mlp"], h)
-        return x + y, aux
-    return x + apply_mlp(cfg, p["mlp"], x=h), jnp.zeros((), jnp.float32)
+        return x + y, aux, {}
+    with jax.named_scope("dense_ffn"):
+        return x + apply_mlp(cfg, p["mlp"], x=h), zero, {}
 
 
 def decoder_layer_train(cfg, p, x, enc_out=None, causal: bool = True,
                         window: Optional[int] = None):
-    """Returns (x, aux_loss). enc_out enables cross-attention (enc-dec)."""
+    """Returns (x, aux_loss, stats). enc_out enables cross-attention."""
     x = _mixer_train(cfg, p, x, window)
     if enc_out is not None and "xattn" in p:
         h = apply_norm(cfg, p["norm_x"], x)
@@ -98,13 +111,14 @@ def decoder_layer_train(cfg, p, x, enc_out=None, causal: bool = True,
 def encoder_layer_train(cfg, p, x):
     h = apply_norm(cfg, p["norm1"], x)
     x = x + attn.attention_train(cfg, p["attn"], h, causal=False)
-    x, _ = _ffn_train(cfg, p, x)
+    x, _, _ = _ffn_train(cfg, p, x)
     return x
 
 
 def run_stack_train(cfg, stacked, x, enc_out=None, causal: bool = True,
                     window: Optional[int] = None, remat: bool = True):
-    """Scan the layer stack. Returns (x, total_aux).
+    """Scan the layer stack. Returns (x, total_aux, stats), ``stats``
+    each layer's stats stacked on a leading layer axis.
 
     ``cfg.remat_block = G`` enables sqrt-remat: an outer (checkpointed) scan
     over L/G layer groups and an inner scan over the G layers of a group —
@@ -115,9 +129,9 @@ def run_stack_train(cfg, stacked, x, enc_out=None, causal: bool = True,
 
     def body(carry, layer_p):
         h, aux = carry
-        h, a = decoder_layer_train(cfg, layer_p, h, enc_out=enc_out,
-                                   causal=causal, window=window)
-        return (h, aux + a), None
+        h, a, stats = decoder_layer_train(cfg, layer_p, h, enc_out=enc_out,
+                                          causal=causal, window=window)
+        return (h, aux + a), stats
 
     init = (x, jnp.zeros((), jnp.float32))
     g = getattr(cfg, "remat_block", 0)
@@ -129,14 +143,15 @@ def run_stack_train(cfg, stacked, x, enc_out=None, causal: bool = True,
         def group_body(carry, gp):
             # inner body checkpointed too: during the group's backward only
             # the G carry boundaries go live, never full layer residuals
-            out, _ = jax.lax.scan(jax.checkpoint(body), carry, gp)
-            return out, None
+            return jax.lax.scan(jax.checkpoint(body), carry, gp)
 
-        (x, aux), _ = jax.lax.scan(jax.checkpoint(group_body), init, grouped)
-        return x, aux
+        (x, aux), stats = jax.lax.scan(jax.checkpoint(group_body), init,
+                                       grouped)
+        return x, aux, jax.tree.map(
+            lambda a: a.reshape(nl, *a.shape[2:]), stats)
     fn = jax.checkpoint(body) if remat else body
-    (x, aux), _ = jax.lax.scan(fn, init, stacked)
-    return x, aux
+    (x, aux), stats = jax.lax.scan(fn, init, stacked)
+    return x, aux, stats
 
 
 def run_encoder_stack(cfg, stacked, x, remat: bool = True):
@@ -194,7 +209,10 @@ def decoder_layer_decode(cfg, p, x, cache, pos):
         h = apply_norm(cfg, p["norm_x"], x)
         x = x + attn.cross_attention_decode(cfg, p["xattn"], h, cache["cross"])
     h = apply_norm(cfg, p["norm2"], x)
-    if cfg.is_moe:
+    if "moe" in p and cfg.experts_held:
+        y, _, _ = moe_mod.moe_held_ffn(cfg, p["moe"], h)
+        x = x + y
+    elif "moe" in p:
         y, _ = moe_mod.moe_ffn(cfg, p["moe"], h)
         if cfg.dense_residual_ff:
             y = y + apply_mlp(cfg, p["mlp"], h)

@@ -1,8 +1,11 @@
 """Shared neural-net layers (pure JAX, params are plain dict pytrees)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ---------------------------------------------------------------------------
 # init helpers
@@ -60,13 +63,44 @@ def rope_frequencies(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, Dh); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, 0.1 m ln(f) + 1 (f > 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies of a ``dim``-wide rope (``yarn`` a
+    ``configs.base.YarnRope``): the high frequencies kept
+    (extrapolated), the low ones divided by ``factor`` (interpolated),
+    and a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over the original context."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * i / dim)
+    inter = extra / yarn.factor
+
+    def corr(rotations):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    mask = 1.0 - ramp  # 1 where the frequency is kept
+    return (inter * (1.0 - mask) + extra * mask).astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float, freqs=None, scale: float = 1.0):
+    """x: (..., S, H, Dh); positions: broadcastable to (..., S).
+
+    ``freqs`` (Dh/2,) replaces the plain rope frequencies (YaRN), and
+    ``scale`` multiplies cos and sin (YaRN's mscale ratio)."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)  # (Dh/2,)
+    if freqs is None:
+        freqs = rope_frequencies(head_dim, theta)  # (Dh/2,)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, Dh/2)
-    cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, Dh/2)
-    sin = jnp.sin(angles)[..., None, :]
+    cos = scale * jnp.cos(angles)[..., None, :]  # (..., S, 1, Dh/2)
+    sin = scale * jnp.sin(angles)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -107,10 +141,21 @@ def init_mlp(key, cfg, d_model=None, d_ff=None):
     }
 
 
+def matmul(cfg, x, w):
+    """x @ w; with ``cfg.matmul_dtype`` both operands are cast to it and
+    the product is accumulated and returned in float32 (bf16 operands
+    under float32 master weights)."""
+    if not cfg.matmul_dtype:
+        return x @ w
+    dt = jnp.dtype(cfg.matmul_dtype)
+    return jnp.dot(x.astype(dt), w.astype(dt),
+                   preferred_element_type=jnp.float32)
+
+
 def apply_mlp(cfg, p, x):
     if cfg.activation in ("swiglu", "geglu"):
-        gate = x @ p["w_gate"]
+        gate = matmul(cfg, x, p["w_gate"])
         act = jax.nn.silu(gate) if cfg.activation == "swiglu" else jax.nn.gelu(gate)
-        return (act * (x @ p["w_up"])) @ p["w_down"]
+        return matmul(cfg, act * matmul(cfg, x, p["w_up"]), p["w_down"])
     h = jax.nn.gelu(x @ p["w_up"] + p["b_up"])
     return h @ p["w_down"] + p["b_down"]

@@ -1,4 +1,5 @@
-"""Top-level model: embedding -> scanned decoder stack -> LM head.
+"""Top-level model: embedding -> (leading dense stack) -> scanned decoder
+stack -> LM head.
 
 One class covers all assigned families (dense / moe / hybrid / ssm / vlm /
 audio). Params are plain dict pytrees; every method is a pure function of
@@ -23,6 +24,7 @@ from repro.models.layers import (
     dense_init,
     embed_init,
     make_norm_params,
+    matmul,
     sinusoidal_positions,
 )
 
@@ -39,9 +41,14 @@ class Model:
         p: Dict[str, Any] = {
             "embed": embed_init(ks[0], cfg.vocab_size, cfg.d_model, dtype),
             "final_norm": make_norm_params(cfg, cfg.d_model),
-            "layers": blocks.init_stack(ks[1], cfg, cfg.num_layers,
+            "layers": blocks.init_stack(ks[1], cfg,
+                                        cfg.num_layers - cfg.first_k_dense,
                                         cross=cfg.is_encdec),
         }
+        if cfg.first_k_dense:
+            p["dense_layers"] = blocks.init_stack(ks[5], cfg,
+                                                  cfg.first_k_dense,
+                                                  dense=True)
         if not cfg.tie_embeddings:
             p["lm_head"] = dense_init(ks[2], cfg.d_model, cfg.vocab_size, dtype)
         if cfg.num_patches:
@@ -65,12 +72,13 @@ class Model:
             x = x + sinusoidal_positions(pos, cfg.d_model)[None].astype(x.dtype)
         return x.astype(jnp.dtype(cfg.compute_dtype))
 
+    @jax.named_scope("lm_head")
     def _logits(self, params, x):
         cfg = self.cfg
         x = apply_norm(cfg, params["final_norm"], x)
         if cfg.tie_embeddings:
             return x @ params["embed"].T
-        return x @ params["lm_head"]
+        return matmul(cfg, x, params["lm_head"])
 
     def _encode(self, params, frames):
         cfg = self.cfg
@@ -85,6 +93,11 @@ class Model:
                window: Optional[int] = None):
         """Full-sequence forward up to (and incl.) trimming non-text
         positions; returns (hidden (B,S,d), aux_loss) — no LM head."""
+        x, aux, _ = self._hidden(params, batch, window)
+        return x, aux
+
+    def _hidden(self, params, batch, window):
+        """``hidden`` and the MoE stack's per-layer stats."""
         cfg = self.cfg
         x = self._embed(params, batch["tokens"])
         enc_out = None
@@ -93,11 +106,15 @@ class Model:
             x = jnp.concatenate([patches, x], axis=1)
         if cfg.is_encdec:
             enc_out = self._encode(params, batch["frames"])
-        x, aux = blocks.run_stack_train(cfg, params["layers"], x,
-                                        enc_out=enc_out, window=window)
+        aux0 = jnp.zeros((), jnp.float32)
+        if cfg.first_k_dense:
+            x, aux0, _ = blocks.run_stack_train(cfg, params["dense_layers"], x,
+                                                window=window)
+        x, aux, stats = blocks.run_stack_train(cfg, params["layers"], x,
+                                               enc_out=enc_out, window=window)
         if cfg.num_patches:
             x = x[:, batch["patches"].shape[1]:]  # loss on text positions only
-        return x, aux
+        return x, aux0 + aux, stats
 
     def apply(self, params, batch: Dict[str, jnp.ndarray],
               window: Optional[int] = None):
@@ -120,15 +137,15 @@ class Model:
         never exists in HBM — see EXPERIMENTS.md §Perf iteration 2.
         """
         labels = batch["labels"]
+        x, aux, stats = self._hidden(params, batch, window)
         if not self.cfg.ce_chunk:
-            logits, aux = self.apply(params, batch, window=window)
+            logits = self._logits(params, x)
             lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
             gold = jnp.take_along_axis(logits.astype(jnp.float32),
                                        labels[..., None], axis=-1)[..., 0]
             ce = jnp.mean(lse - gold)
-            return ce + aux, {"ce": ce, "aux": aux}
+            return ce + aux, {"ce": ce, "aux": aux, **stats}
 
-        x, aux = self.hidden(params, batch, window=window)
         cfg = self.cfg
         x = apply_norm(cfg, params["final_norm"], x)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -158,14 +175,21 @@ class Model:
         total, _ = jax.lax.scan(jax.checkpoint(body), jnp.zeros((), jnp.float32),
                                 (xc, lc, vc))
         ce = total / t
-        return ce + aux, {"ce": ce, "aux": aux}
+        return ce + aux, {"ce": ce, "aux": aux, **stats}
 
     # ------------------------------------------------------------------ serve
     def init_cache(self, batch: int, cache_len: int):
+        """The stack's layer-stacked cache; with leading dense layers, theirs
+        under ``dense_layers``."""
         cfg = self.cfg
         dtype = jnp.dtype(cfg.compute_dtype)
-        return blocks.init_stack_cache(cfg, cfg.num_layers, batch, cache_len,
-                                       dtype, cross=cfg.is_encdec)
+        cache = blocks.init_stack_cache(cfg, cfg.num_layers - cfg.first_k_dense,
+                                        batch, cache_len, dtype,
+                                        cross=cfg.is_encdec)
+        if cfg.first_k_dense:
+            cache["dense_layers"] = blocks.init_stack_cache(
+                cfg, cfg.first_k_dense, batch, cache_len, dtype)
+        return cache
 
     def prefill_cross(self, params, cache, frames):
         """Enc-dec only: run the encoder, fill per-layer cross K/V caches."""
@@ -190,7 +214,14 @@ class Model:
         if not cfg.rope_theta:
             x = x + sinusoidal_positions(jnp.full((1,), pos), cfg.d_model)[None].astype(x.dtype)
         x = x.astype(jnp.dtype(cfg.compute_dtype))
+        cache = dict(cache)
+        dense = cache.pop("dense_layers", None)
+        if dense is not None:
+            x, dense = blocks.run_stack_decode(cfg, params["dense_layers"], x,
+                                               dense, pos)
         x, cache = blocks.run_stack_decode(cfg, params["layers"], x, cache, pos)
+        if dense is not None:
+            cache["dense_layers"] = dense
         return self._logits(params, x), cache
 
     # ------------------------------------------------------------- utilities
@@ -210,7 +241,7 @@ class Model:
         expert_leaves = ["w_gate", "w_up", "w_down"]
         moe = params["layers"]["moe"]
         routed = sum(int(moe[k].size) for k in expert_leaves)
-        active = routed * cfg.experts_per_token // cfg.num_experts
+        active = routed * cfg.experts_per_token // cfg.held_experts[1]
         return total - routed + active
 
 

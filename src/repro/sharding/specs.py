@@ -173,7 +173,10 @@ def _base_rule(name: str, path: str, shape: Tuple[int, ...], mesh,
                 None]
     if name == "router":
         return [None, None]
-    if name in ("wq", "wk", "wv", "w_gate", "w_up", "in_proj", "dt_proj"):
+    # MLA: wkv_a (d, kv_lora_rank + rope) and wkv_b (kv_lora_rank,
+    # H * (nope + v)) are column-parallel like wq; kv_norm replicates
+    if name in ("wq", "wk", "wv", "wkv_a", "wkv_b", "w_gate", "w_up",
+                "in_proj", "dt_proj"):
         out_ok = _div(shape[-1], msize)
         in_ok = fsdp and _div(shape[-2], dsize)
         return [DATA_AXIS if in_ok else None, MODEL_AXIS if out_ok else None]
