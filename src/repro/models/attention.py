@@ -4,10 +4,11 @@ single-token decode against full or ring KV caches. Latent attention
 (MLA, DeepSeek-V2: low-rank KV, a rope key shared across heads, YaRN)
 when ``cfg.kv_lora_rank`` is set.
 
-The chunked path is pure JAX (lax.scan + online softmax) so it lowers on any
-backend — it is the XLA fallback of the Pallas flash kernel in
-``repro.kernels.flash_attn`` (used on real TPUs; validated against the same
-reference in tests).
+Long causal sequences (``causal_long``) run a block-skipping Pallas kernel
+with its own backward on the TPU (``repro.kernels.flash_attn.ops.
+causal_attention``). Elsewhere, and for lengths its block does not divide,
+they run the chunked path: pure JAX (lax.scan + online softmax), so it
+lowers on any backend, and the tests' reference for the kernel.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attn import ops as flash_ops
 from repro.models.layers import (
     apply_rope,
     dense_init,
@@ -177,8 +179,18 @@ def _sliding_window_attention(q, k, v, window: int, q_chunk: int):
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
 
 
-FULL_ATTN_MAX_SEQ = 4096  # above this, the chunked path is used
+FULL_ATTN_MAX_SEQ = 4096  # above this, causal_long is used
 Q_CHUNK = 512
+
+
+def causal_long(q, k, v, scale: Optional[float] = None):
+    """Causal attention over a sequence too long for materialised scores:
+    the Pallas kernel on the TPU when its block divides S, else the
+    chunked XLA scan. Returns v's dtype, as the chunked path does."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    if jax.default_backend() == "tpu" and flash_ops.causal_fits(q.shape[1]):
+        return flash_ops.causal_attention(q, k, v, scale).astype(v.dtype)
+    return _chunked_causal_attention(q, k, v, Q_CHUNK, scale=scale)
 
 
 def attention_train(cfg, p, x, kv_x=None, causal: bool = True,
@@ -205,7 +217,7 @@ def attention_train(cfg, p, x, kv_x=None, causal: bool = True,
             else _full_attention(q, k, v, causal=True, window=window)
     elif causal and (s > FULL_ATTN_MAX_SEQ or
                      (getattr(cfg, "force_chunked_attn", False) and s > Q_CHUNK)):
-        out = _chunked_causal_attention(q, k, v, Q_CHUNK)
+        out = causal_long(q, k, v)
     else:
         out = _full_attention(q, k, v, causal=causal)
     return out.reshape(b, s, -1) @ p["wo"]
@@ -288,7 +300,7 @@ def mla_train(cfg, p, x, positions=None):
     q, k, v = _mla_qkv(cfg, p, x, positions)
     scale = mla_softmax_scale(cfg)
     if s > FULL_ATTN_MAX_SEQ or (cfg.force_chunked_attn and s > Q_CHUNK):
-        out = _chunked_causal_attention(q, k, v, Q_CHUNK, scale=scale)
+        out = causal_long(q, k, v, scale)
     else:
         out = _full_attention(q, k, v, causal=True, scale=scale)
     return matmul(cfg, out.reshape(b, s, -1), p["wo"])

@@ -174,3 +174,37 @@ def test_sharded_server_pass_compiles_on_four_chips(topo, whisper_np):
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes)
     assert per_device < V5E_HBM_BYTES // 2, per_device
+
+
+def test_causal_attention_kernel_compiles_at_the_mla_shape(one_chip):
+    """The causal kernel's value and gradients at DeepSeek-V2-Lite's MLA
+    shape (B=1, S=8,192, 16 heads, q/k 192, v 128) lower to its three
+    passes, with no float32 (..., 512, 8192) chunk scores, and need fewer
+    temporaries than the chunked XLA path's (0.168 GB against 0.823 GB
+    when written)."""
+    from repro.kernels.flash_attn.ops import causal_attention
+    from repro.models.attention import Q_CHUNK, _chunked_causal_attention
+
+    b, s, h, dqk, dv, f32 = 1, 8192, 16, 192, 128, jnp.float32
+    shapes = (((b, s, h, dqk), f32), ((b, s, h, dqk), f32),
+              ((b, s, h, dv), f32))
+
+    def value_and_grad(attention):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attention(q, k, v, 0.1147214)),
+            argnums=(0, 1, 2))
+
+    kernel = _compile(value_and_grad(causal_attention), one_chip, *shapes)
+    chunked = _compile(value_and_grad(
+        lambda q, k, v, scale: _chunked_causal_attention(
+            q, k, v, Q_CHUNK, scale=scale)), one_chip, *shapes)
+    text = kernel.as_text()
+    for phase in ("fwd", "dq", "dkv"):
+        calls = re.findall(
+            rf"%splash_mha_{phase}_\w+(?:\.\d+)? = [^\n]*custom-call\(", text)
+        assert len(calls) == 1, phase
+    chunk_scores = re.compile(r"f32\[[\d,]*512,8192\]")
+    assert chunk_scores.search(chunked.as_text())
+    assert not chunk_scores.search(text)
+    temp = kernel.memory_analysis().temp_size_in_bytes
+    assert temp < chunked.memory_analysis().temp_size_in_bytes, temp
