@@ -1,95 +1,10 @@
-"""Shared benchmark utilities (timing, CSV output, run metadata)."""
+"""CSV output and a terminal sparkline for the paper-reproduction scripts."""
 from __future__ import annotations
 
-import datetime
-import json
 import os
-import resource
-import subprocess
-import sys
-import time
-from typing import Callable, Dict, List
-
-import jax
+from typing import List
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
-_REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
-
-
-def _git_sha() -> str:
-    try:
-        return subprocess.run(
-            ["git", "rev-parse", "HEAD"], capture_output=True, text=True,
-            cwd=_REPO_ROOT, check=True).stdout.strip()
-    except (subprocess.CalledProcessError, OSError):
-        return "unknown"
-
-
-def peak_rss_mb() -> float:
-    """Peak resident set size of this process so far, in MiB.
-
-    ``getrusage`` reports ``ru_maxrss`` in KiB on Linux and bytes on
-    macOS; monotone over the process lifetime, so sampling it before and
-    after a phase bounds that phase's host-memory high-water mark — the
-    number ``bench_population_scale.py`` asserts is flat in N.
-    """
-    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-    scale = 1024.0 * 1024.0 if sys.platform == "darwin" else 1024.0
-    return float(rss) / scale
-
-
-def run_metadata() -> Dict[str, object]:
-    """Provenance stamp for every ``BENCH_*.json``: which software, which
-    hardware, which commit, and when.  ``check_regression.py`` reads
-    ``backend``/``device_kind`` to refuse cross-backend comparisons —
-    absolute events/sec figures are meaningless across hardware classes.
-    ``peak_rss_mb`` records the host high-water mark at stamp time (the
-    benches stamp at exit, so it covers the whole run).
-    ``ring_codec`` / ``ring_bytes_per_device`` record the active
-    compressed-version-store configuration of the last ring the process
-    built (``core/version_store.ring_provenance``; null when the bench
-    never built one) so every BENCH_*.json says which ring layout its
-    numbers were measured under."""
-    devices = jax.devices()
-    from repro.core.version_store import ring_provenance
-    return {
-        "jax_version": jax.__version__,
-        "backend": jax.default_backend(),
-        "device_kind": devices[0].device_kind if devices else "unknown",
-        "device_count": jax.device_count(),
-        "process_count": jax.process_count(),
-        "git_sha": _git_sha(),
-        "peak_rss_mb": round(peak_rss_mb(), 1),
-        "timestamp_utc": datetime.datetime.now(
-            datetime.timezone.utc).isoformat(timespec="seconds"),
-        **ring_provenance(),
-    }
-
-
-def write_bench_json(path: str, doc: dict) -> str:
-    """Write a benchmark result dict with the ``meta`` provenance stamp.
-
-    An existing ``meta`` dict is merged in (its keys win), so benches can
-    carry bench-specific notes alongside the standard provenance fields."""
-    doc = dict(doc)
-    doc["meta"] = {**run_metadata(), **doc.get("meta", {})}
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    return path
-
-
-def time_fn(fn: Callable, *args, iters: int = 5, warmup: int = 2) -> float:
-    """Median wall-time per call in microseconds (blocks on results)."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2] * 1e6
 
 
 def write_csv(name: str, header: List[str], rows: List[List]) -> str:

@@ -190,8 +190,7 @@ class FLConfig:
     probe_batch: int = 4  # eq.-4 probe sequences per data-parallel group
     # device-resident server pass (DESIGN.md §3): auto picks the fused
     # Pallas kernel on TPU and the pure-jnp reference body elsewhere
-    server_pass_mode: str = "auto"  # auto | reference | batched | fused
-    server_pass_block_n: int = 0  # kernel N-tile; 0 = auto (lane-aligned)
+    server_pass_mode: str = "auto"  # auto | reference | fused
     # compressed version store (core/version_store.py, DESIGN.md §11)
     ring_codec: str = "f32"  # f32 | int8 | delta (version_store.CODECS)
     ring_qblock: int = 256  # int8: params per affine quantization block

@@ -151,7 +151,7 @@ def make_round_body(loss_fn: Callable, fl: FLConfig, *,
         (``core/version_store.py``) into ``apply_server_round`` — the
         codec computed them against its compressed rows directly, so the
         server pass must not recompute them from the decoded bases."""
-        spec = make_flat_spec(params, fl.server_pass_block_n, mesh=mesh)
+        spec = make_flat_spec(params, mesh=mesh)
         if flat_bases:
             bases_flat = bases
             bases = unflatten_stacked(spec, bases_flat, params)
@@ -221,10 +221,10 @@ def make_ring_round(loss_fn: Callable, fl: FLConfig, *,
     body = make_round_body(loss_fn, fl, mesh=mesh)
     codec = resolve_codec(fl)
     mode, interpret = resolve_mode(fl.server_pass_mode)
-    use_kernel = mode in ("batched", "fused")
+    use_kernel = mode == "fused"
 
     def ring_round(params, ring, slots, batch, probe, sizes, taus, new_slot):
-        spec = make_flat_spec(params, fl.server_pass_block_n, mesh=mesh)
+        spec = make_flat_spec(params, mesh=mesh)
         with jax.named_scope("ring_decode"):
             bases = codec.decode(spec, ring, slots)  # (K, n_padded) rows
         dists = None

@@ -25,11 +25,12 @@ Pallas kernels' ``N % block_n == 0`` contract holds for arbitrary models.
 
 Modes (``FLConfig.server_pass_mode``):
   reference : pure-jnp body — one XLA program, runs everywhere;
-  batched   : eq. 3 via ``sq_dists_pallas`` (one HBM pass for all K) and
-              eq. 5 via ``weighted_sum_pallas`` — two kernel launches;
-  fused     : ``fused_server_pallas`` — eq. 3 + weighting + eq. 5 in a
-              single two-phase kernel launch (bases and deltas each read
-              from HBM exactly once);
+  fused     : the Pallas kernels — ``fused_server_pallas`` (eq. 3 +
+              weighting + eq. 5 in one two-phase launch, bases and deltas
+              each read from HBM once) where it can run; under a model
+              mesh, or with distances a ring codec computed, the
+              two-phase ``sq_dists_pallas`` / ``weighted_sum_pallas``
+              tiles, since the weighting needs the whole eq. 3 distances;
   auto      : fused on TPU, reference elsewhere (Mosaic kernels need a
               TPU; interpret mode is validation-only).
 
@@ -70,7 +71,7 @@ from repro.sharding.specs import MODEL_AXIS, info_pspec, mesh_axis_size
 
 logger = logging.getLogger(__name__)
 
-MODES = ("auto", "reference", "batched", "fused")
+MODES = ("auto", "reference", "fused")
 
 
 def resolve_mode(mode: str, interpret: Optional[bool] = None) -> Tuple[str, bool]:
@@ -79,7 +80,7 @@ def resolve_mode(mode: str, interpret: Optional[bool] = None) -> Tuple[str, bool
     Mosaic kernels compile only for TPU; everywhere else interpret mode
     would run them tile-by-tile in Python (validation-only), so ``auto``
     falls back to the pure-jnp reference body — still one compiled,
-    device-resident program. An explicit ``fused``/``batched`` request off
+    device-resident program. An explicit ``fused`` request off
     TPU is honoured in interpret mode but warns, so the silent-slowdown
     failure mode is visible.
     """
@@ -94,9 +95,9 @@ def resolve_mode(mode: str, interpret: Optional[bool] = None) -> Tuple[str, bool
         if not on_tpu:
             logger.info(
                 "server_pass_mode='auto' resolved to 'reference' on backend "
-                "%r: the fused/batched Pallas kernels are Mosaic programs "
+                "%r: the Pallas kernels are Mosaic programs "
                 "and compile only for TPU", backend)
-    elif mode in ("batched", "fused") and not on_tpu and interpret:
+    elif mode == "fused" and not on_tpu and interpret:
         # standardized logging (obs.configure_logging, DESIGN.md §9):
         # launchers set the level once; this used to be a warnings.warn
         logger.warning(
@@ -253,7 +254,7 @@ def apply_server_round(x: jnp.ndarray, bases: jnp.ndarray,
     weighting is computed replicated, and the eq. 5 reduction (over K)
     finishes per-shard with no further collective.
     """
-    if mode not in ("reference", "batched", "fused"):
+    if mode not in ("reference", "fused"):
         raise ValueError(f"unknown concrete mode {mode!r}")
     p = statistical_effect(losses, data_sizes)
     k = bases.shape[0]
@@ -269,28 +270,23 @@ def apply_server_round(x: jnp.ndarray, bases: jnp.ndarray,
             x, bases, deltas, losses, p, taus, mask, fl, mode=mode,
             block=block, interpret=interpret, mesh=mesh, sq_dists=sq_dists)
 
-    if sq_dists is not None:
-        dists = sq_dists.astype(jnp.float32)
-        upd, s, w = _weight_and_reduce(
-            dists, deltas, p, taus, mask, fl,
-            use_kernel=(mode in ("batched", "fused")), block=block,
-            interpret=interpret)
-        new_x = x - upd
-    elif mode == "fused":
+    if mode == "fused" and sq_dists is None:
         upd, dists, w = _ops.server_update(
             x, bases, deltas, p, taus, mask, policy=fl.weighting,
             eta_g=fl.global_lr, s_min=fl.s_min, poly_a=fl.poly_a,
             hinge_a=fl.hinge_a, hinge_b=fl.hinge_b,
             normalize=fl.normalize, block_n=block, interpret=interpret)
         s = staleness_degree(dists, arrival_mask=mask)
-        new_x = x - upd
     else:
-        dists = _sq_dists(x, bases, use_kernel=(mode == "batched"),
-                          block=block, interpret=interpret)
+        # the reference body, or ``fused`` with a codec's distances (the
+        # one-launch kernel's phase 0 would only recompute them)
+        dists = (_sq_dists(x, bases, use_kernel=False, block=block,
+                           interpret=interpret)
+                 if sq_dists is None else sq_dists.astype(jnp.float32))
         upd, s, w = _weight_and_reduce(
             dists, deltas, p, taus, mask, fl,
-            use_kernel=(mode == "batched"), block=block, interpret=interpret)
-        new_x = x - upd
+            use_kernel=(mode == "fused"), block=block, interpret=interpret)
+    new_x = x - upd
 
     info = {"sq_dists": dists, "staleness": s, "stat_effect": p,
             "weights": w, "fresh_loss": losses}
@@ -343,15 +339,14 @@ def _apply_server_round_sharded(x, bases, deltas, losses, p, taus, mask,
     built, taus cast, block picked per-shard). The fused single-launch
     kernel folds the weighting into the kernel, but the weighting needs
     the GLOBAL eq. 3 distances — which only exist after the cross-shard
-    psum — so under sharding both kernel modes (``batched`` and
-    ``fused``) run the two-phase tiles (``sq_dists_pallas`` +
-    ``weighted_sum_pallas``) per shard; the shape of the communication
-    (one (K,) psum) is identical either way. Precomputed ``sq_dists``
-    (the compressed-ring codecs) arrive already globally reduced, so
-    that path carries them in replicated and skips the psum entirely —
-    the round then has NO collective beyond the final output layout.
+    psum — so under sharding ``fused`` runs the two-phase tiles
+    (``sq_dists_pallas`` + ``weighted_sum_pallas``) per shard.
+    Precomputed ``sq_dists`` (the compressed-ring codecs) arrive already
+    globally reduced, so that path carries them in replicated and skips
+    the psum entirely — the round then has NO collective beyond the
+    final output layout.
     """
-    use_kernel = mode in ("batched", "fused")
+    use_kernel = mode == "fused"
 
     if sq_dists is not None:
         def shard_body_pre(x_s, d_s, p_, taus_, mask_, dists):
@@ -428,7 +423,7 @@ def make_server_pass(fl: FLConfig,
     @jax.jit
     def pass_fn(params, deltas_st, bases_st, probes, probe_mask,
                 data_sizes, taus, precomputed_losses=None):
-        spec = make_flat_spec(params, fl.server_pass_block_n, mesh=mesh)
+        spec = make_flat_spec(params, mesh=mesh)
         x = flatten_tree(spec, params)
         d = flatten_stacked(spec, deltas_st)
         b = flatten_stacked(spec, bases_st)

@@ -25,14 +25,13 @@ actually needs:
 
 * **Telemetry.** Sustained uploads folded/sec, round-latency quantiles
   (p50/p99), queue-depth high-water mark, per-reason rejection counts,
-  and the K trajectory — the numbers ``benchmarks/bench_serve.py`` gates
-  on. The counters live on an ``obs.metrics`` registry (DESIGN.md §9) —
-  ``controller.counters`` and ``metrics()`` are stable views of it, so
-  the historical dict shape is unchanged while the registry snapshot
-  gives the JSONL sink / nightly diffing the same numbers with labeled
-  series. An optional ``obs.trace.Tracer`` times the round lifecycle
-  (``collect_window`` open -> K-th fold, ``contribute`` per fold,
-  ``apply`` per round) as Chrome-trace spans.
+  and the K trajectory. The counters live on an ``obs.metrics`` registry
+  (DESIGN.md §9) — ``controller.counters`` and ``metrics()`` are stable
+  views of it, so the historical dict shape is unchanged while the
+  registry snapshot gives the JSONL sink / snapshot diffs the same
+  numbers with labeled series. An optional ``obs.trace.Tracer`` times
+  the round lifecycle (``collect_window`` open -> K-th fold,
+  ``contribute`` per fold, ``apply`` per round) as Chrome-trace spans.
 
 Time is injected by the caller (``now``): the driver below runs on the
 sim/ scenario clock so tests and CI are deterministic, while a real

@@ -441,17 +441,6 @@ def _checked_host_dict(codec, spec, depth: int, host) -> Dict[str, Any]:
 # store construction + host round-trip (the engine/population entry points)
 # ---------------------------------------------------------------------------
 
-# provenance of the most recently built store, stamped into BENCH_*.json
-# by benchmarks/common.run_metadata() (single-process benchmarking only —
-# this is telemetry, not program state)
-_LAST_BUILT: Dict[str, Any] = {"ring_codec": None,
-                               "ring_bytes_per_device": None}
-
-
-def ring_provenance() -> Dict[str, Any]:
-    """{ring_codec, ring_bytes_per_device} of the last store built."""
-    return dict(_LAST_BUILT)
-
 
 def build_ring(init_params: Any, fl, *, mesh: Optional[Any] = None,
                shard_ring: bool = True, rows: Optional[Any] = None):
@@ -467,7 +456,7 @@ def build_ring(init_params: Any, fl, *, mesh: Optional[Any] = None,
     from repro.core.server_pass import flatten_tree, make_flat_spec
     from repro.launch.multihost import put_with_sharding
 
-    spec = make_flat_spec(init_params, fl.server_pass_block_n, mesh=mesh)
+    spec = make_flat_spec(init_params, mesh=mesh)
     depth = fl.max_staleness + 1
     codec = resolve_codec(fl)
     if rows is None:
@@ -483,10 +472,6 @@ def build_ring(init_params: Any, fl, *, mesh: Optional[Any] = None,
         placed = [put_with_sharding(leaf, mesh, ps)
                   for leaf, ps in zip(leaves, pspecs)]
         state = jax.tree.unflatten(treedef, placed)
-    _LAST_BUILT.update(
-        ring_codec=codec.name,
-        ring_bytes_per_device=codec.device_bytes(
-            spec, depth, shards if (shard_ring and mesh is not None) else 1))
     return spec, state
 
 

@@ -20,7 +20,7 @@ def int8_sq_dists(x: jnp.ndarray, codes: jnp.ndarray, scales: jnp.ndarray,
     there is no pad/slice here. ``use_kernel`` picks the fused Mosaic
     kernel (TPU, or interpret-mode validation); otherwise the jnp
     reference runs — same dispatch convention as the server pass's
-    batched/fused vs reference modes.
+    fused vs reference modes.
     """
     x = x.astype(jnp.float32)
     if not use_kernel:

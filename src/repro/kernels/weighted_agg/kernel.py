@@ -7,7 +7,9 @@ memory-bound streaming: for each parameter tile it must
 
 ``fused_server_pallas`` does (a) and (b) plus the weighting policy in a
 single two-phase launch (see its docstring); the two single-purpose
-kernels below remain as the batched mode and the building blocks.
+kernels below are the two-phase path the server pass takes where the
+weighting needs distances from outside the launch (a model mesh's psum,
+or a ring codec's own eq. 3).
 
 All kernels tile the flattened parameter axis into VMEM-resident blocks
 (lane-aligned multiples of 128; K rides the sublane dimension), so one HBM
@@ -86,18 +88,16 @@ def weighted_sum_pallas(deltas: jnp.ndarray, weights: jnp.ndarray,
 
 
 def _fused_server_kernel(x_ref, b_ref, d_ref, p_ref, tau_ref, m_ref,
-                         upd_ref, dist_ref, w_ref, *,
-                         policy: str, eta_g: float, s_min: float,
-                         poly_a: float, hinge_a: float, hinge_b: float,
-                         normalize: str, eps: float):
+                         upd_ref, dist_ref, w_ref, *, weigh, eta_g: float):
     """Whole eq. 3 + weighting + eq. 5 server reduction in ONE kernel.
 
     Two-phase sequential grid (ph, i) with ph in {0, 1}, i over N-tiles:
       phase 0  accumulates per-client ||x - base_k||^2 into the resident
                (K, 1) dist block (bases stream through VMEM once);
-      boundary (ph=1, i=0) turns distances into eq.-3 staleness degrees,
-               applies the weighting policy + mean normalisation in-VMEM
-               (a K-vector — no host round-trip, no second kernel launch);
+      boundary (ph=1, i=0) applies ``weigh`` — eq. 3's staleness degrees,
+               the weighting policy and its normalisation — to the
+               resident (K, 1) columns in-VMEM (no host round-trip, no
+               second kernel launch);
       phase 1  streams the deltas once, reducing sum_k w_k * d[k, tile]
                scaled by eta_g / k_eff straight into the output tiles.
 
@@ -123,30 +123,8 @@ def _fused_server_kernel(x_ref, b_ref, d_ref, p_ref, tau_ref, m_ref,
 
     @pl.when(jnp.logical_and(ph == 1, i == 0))
     def _weights():
-        # eq. 3 — staleness degree: min reference over ARRIVED slots only
-        # (absent slots park on max(d)); mirrors core/weighting.py exactly
-        d = jnp.maximum(dist_ref[...], 0.0)  # (K, 1)
-        mn = jnp.min(jnp.where(m_ref[...] > 0, d, jnp.max(d)))
-        s = jnp.clip((mn + eps) / (d + eps), 0.0, 1.0)
-        p = p_ref[...]
-        if policy == "paper":
-            w = p / jnp.maximum(s, s_min)
-        elif policy == "multiplicative":
-            w = p * s
-        elif policy in ("fedbuff", "fedasync_constant"):
-            w = jnp.ones_like(p)
-        elif policy == "fedasync_hinge":
-            t = tau_ref[...]
-            w = jnp.where(t <= hinge_b, jnp.ones_like(t),
-                          1.0 / jnp.maximum(hinge_a * (t - hinge_b), 1e-12))
-        else:  # polynomial / fedasync / fedasync_poly
-            w = (1.0 + tau_ref[...]) ** (-poly_a)
-        mask = m_ref[...]
-        w = w * mask
-        if normalize == "mean":
-            denom_n = jnp.maximum(jnp.sum(mask), 1.0)
-            w = w * denom_n / jnp.maximum(jnp.sum(w), 1e-12)
-        w_ref[...] = w
+        w_ref[...] = weigh(dist_ref[...], p_ref[...], tau_ref[...],
+                           m_ref[...])
 
     @pl.when(ph == 1)
     def _reduce():
@@ -174,12 +152,19 @@ def fused_server_pallas(x: jnp.ndarray, bases: jnp.ndarray,
     scale. N % block_n == 0 (use the ops wrapper for padding); the
     kernel runs ``fit_block(block_n, K)``.
     """
-    if policy not in ("paper", "multiplicative", "fedbuff", "polynomial",
-                      "fedasync", "fedasync_constant", "fedasync_hinge",
-                      "fedasync_poly"):
+    # imported here: repro.core's package imports the server pass, which
+    # imports this module
+    from repro.core import weighting
+
+    if policy not in weighting.POLICIES:
         raise ValueError(f"unknown policy {policy!r}")
-    if normalize not in ("mean", "none"):
-        raise ValueError(f"unknown normalize {normalize!r}")
+
+    def weigh(dists, p, tau, mask):
+        s = weighting.staleness_degree(dists, eps, arrival_mask=mask)
+        return weighting.contribution_weights(
+            policy, p, s, tau, s_min=s_min, poly_a=poly_a, hinge_a=hinge_a,
+            hinge_b=hinge_b, normalize=normalize, arrival_mask=mask)
+
     k, n = bases.shape
     assert deltas.shape == (k, n) and x.shape == (n,)
     assert n % block_n == 0, (n, block_n)
@@ -187,10 +172,8 @@ def fused_server_pallas(x: jnp.ndarray, bases: jnp.ndarray,
     tiles = n // block_n
     grid = (2, tiles)
     col2 = lambda a: a.astype(jnp.float32).reshape(k, 1)
-    kernel = functools.partial(
-        _fused_server_kernel, policy=policy, eta_g=eta_g, s_min=s_min,
-        poly_a=poly_a, hinge_a=hinge_a, hinge_b=hinge_b,
-        normalize=normalize, eps=eps)
+    kernel = functools.partial(_fused_server_kernel, weigh=weigh,
+                               eta_g=eta_g)
     upd, dists, w = pl.pallas_call(
         kernel,
         grid=grid,

@@ -8,8 +8,7 @@ client_fl), so the shared surface cannot drift: a flag rename or a new
 default lands everywhere or nowhere.
 
 ``enable_compile_cache`` places JAX's persistent compilation cache for
-every entry point (the launchers, ``benchmarks/run.py`` and
-``chip_smoke.py``).
+every entry point (the launchers and ``chip_smoke.py``).
 
 ``ObsStack.from_args`` is the runtime counterpart: it turns the obs
 flags into the registry / tracer / windowed profiler / JSONL sink

@@ -32,8 +32,8 @@ def make_round_mesh(data: int = 1, model: int = 0):
     (``sharding/specs.ring_pspec``: R * Np / model per-device floats,
     DESIGN.md §6). ``model=0`` spreads all remaining devices on the
     model axis. Unlike ``make_host_mesh`` this does not require using
-    every device — scale-out sweeps (benchmarks/bench_shard_scale.py) pin
-    subsets of the forced-host-device pool.
+    every device — a sweep over device counts can pin subsets of the
+    forced-host-device pool.
 
     In a multi-process session (``jax.distributed`` initialized via
     ``launch/multihost.initialize``) this delegates to

@@ -1,5 +1,6 @@
-"""Serving launcher: batched autoregressive decode of the trained global
-model (what a deployed FL system does with the aggregated weights).
+"""Serving launcher: autoregressive decode of the trained global model,
+a batch of prompts at a time (what a deployed FL system does with the
+aggregated weights).
 
 Smoke mode runs a reduced config on CPU: prefill via decode loop over the
 prompt, then N generation steps, reporting tokens/s.

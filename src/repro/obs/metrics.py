@@ -5,9 +5,8 @@ Three deliberate constraints shape it:
 
 * **Dependency-free and allocation-light.** A counter increment is one
   attribute add on a long-lived object — cheap enough to sit on the
-  serving fold path and the engine dispatch path, whose throughput the
-  nightly gate pins (< 5% overhead budget on the default lane). No
-  prometheus client, no background threads, no locks (the runners are
+  serving fold path and the engine dispatch path. No prometheus
+  client, no background threads, no locks (the runners are
   single-threaded host loops; a real transport front-end would own its
   own registry per worker).
 
@@ -15,8 +14,7 @@ Three deliberate constraints shape it:
   get-or-creates, so call sites never hold module globals; repeated
   lookups return the same instrument. ``snapshot()`` flattens every
   series to ``name{k=v,...} -> float`` — the stable export surface the
-  JSONL sink writes and ``benchmarks/check_regression.py``-style diffing
-  consumes.
+  JSONL sink writes and snapshot diffs consume.
 
 * **Multihost merging is a pure function over snapshots.** Under the
   multi-controller model (DESIGN.md §7) every process runs the same host

@@ -269,8 +269,8 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
 
     # ---- observability plane (DESIGN.md §9) ----------------------------
     # EVERY XLA dispatch of the round program goes through this one
-    # wrapper, so the registry counter — the number the nightly
-    # launch-count gate reads via SimResult.num_launches — cannot miss a
+    # wrapper, so the registry counter — the number the launch-count
+    # tests read via SimResult.num_launches — cannot miss a
     # dispatch site the way a hand-maintained `num_launches += 1` could
     # (e.g. a future final-eval or warmup path calling chunk_step
     # directly). Each call records its phases as spans (``prepare``,

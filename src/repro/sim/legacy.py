@@ -3,9 +3,8 @@
 ``run_async_legacy`` is the original event-driven heapq loop: one jitted
 ``local_update`` dispatch and one ``AsyncServer.receive`` per client
 upload. It is kept as the semantic reference for the vectorized engine
-(tests/test_sim_engine.py checks round-log parity event-for-event) and as
-the baseline side of benchmarks/bench_sim_engine.py. New code should use
-``repro.sim.engine.run_vectorized`` (the default behind
+(tests/test_sim_engine.py checks round-log parity event-for-event). New
+code should use ``repro.sim.engine.run_vectorized`` (the default behind
 ``repro.core.run_async``).
 
 Both runners draw client durations from ``ClientBehavior``'s per-client

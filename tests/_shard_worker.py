@@ -78,7 +78,7 @@ def server_pass_errors(params, mesh, fl, mode, k=8, seed=0,
     taus = jnp.arange(k, dtype=jnp.float32)
 
     def run(mesh_):
-        spec = make_flat_spec(params, fl.server_pass_block_n, mesh=mesh_)
+        spec = make_flat_spec(params, mesh=mesh_)
         new_x, info = apply_server_round(
             flatten_tree(spec, params), flatten_stacked(spec, bases),
             flatten_stacked(spec, deltas), losses, sizes, taus, fl,
@@ -255,14 +255,14 @@ def run_all():
     report = {"devices": len(jax.devices())}
     # acceptance gate: lenet_fmnist flat pass, 8-way model sharding
     lenet = init_lenet(jax.random.PRNGKey(0))
-    for mode in ("reference", "batched"):
+    for mode in ("reference", "fused"):
         report[f"lenet_pass_{mode}"] = server_pass_errors(
             lenet, mesh_m8, fl, mode)
     report["lenet_pass_d2m4"] = server_pass_errors(lenet, mesh_d2m4, fl,
                                                    "reference")
     # block_n=0 default on a tiny tree: per-shard tile must stay valid
     report["small_pass_default_block"] = server_pass_errors(
-        {"w": jnp.linspace(-1.0, 1.0, 100)}, mesh_m8, fl, "batched", k=4,
+        {"w": jnp.linspace(-1.0, 1.0, 100)}, mesh_m8, fl, "fused", k=4,
         default_block=True)
     report["engine"] = engine_errors(mesh_d2m4)
     report["cohort"] = cohort_errors(mesh_d2m4)

@@ -8,7 +8,6 @@ import json
 import logging
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -52,9 +51,6 @@ from repro.sim.engine import run_vectorized
 from repro.sim.population import run_population
 from repro.sim.scenarios import get_scenario
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:  # benchmarks/ is a repo-root namespace package
-    sys.path.insert(0, ROOT)
 
 
 # ---------------------------------------------------------------------------
@@ -348,8 +344,8 @@ def _upload(ctrl, i, tau=0, t=0.0):
 
 class TestServingRegistryParity:
     """The counters moved onto an obs registry; the historical
-    ``metrics()`` dict shape (what bench_serve.py gates on) must be
-    unchanged, and the registry snapshot must mirror every counter."""
+    ``metrics()`` dict shape must be unchanged, and the registry
+    snapshot must mirror every counter."""
 
     SERIES = {
         "admitted": "serve_admitted_total",
@@ -667,51 +663,3 @@ class TestEngineSpans:
         np.testing.assert_array_equal(
             np.asarray(resumed.final_state.params["w"]),
             np.asarray(full.final_state.params["w"]))
-
-
-# ---------------------------------------------------------------------------
-# bench provenance
-# ---------------------------------------------------------------------------
-
-
-class TestBenchProvenance:
-    def test_run_metadata_keys(self):
-        from benchmarks.common import run_metadata
-
-        meta = run_metadata()
-        for key in ("jax_version", "backend", "device_kind", "device_count",
-                    "process_count", "git_sha", "timestamp_utc"):
-            assert key in meta, key
-        assert meta["backend"] == jax.default_backend()
-        assert meta["device_count"] >= 1 and meta["process_count"] >= 1
-
-    def test_write_bench_json_stamps_and_merges_meta(self, tmp_path):
-        from benchmarks.common import write_bench_json
-
-        path = write_bench_json(str(tmp_path / "b.json"),
-                                {"x": 1, "meta": {"note": "kept"}})
-        doc = json.load(open(path))
-        assert doc["x"] == 1
-        assert doc["meta"]["note"] == "kept"  # bench-specific keys win
-        assert doc["meta"]["backend"] == jax.default_backend()
-
-    def test_cross_backend_comparison_refused(self):
-        from benchmarks.check_regression import backend_mismatch
-
-        tpu = {"meta": {"backend": "tpu", "device_kind": "TPU v4"}}
-        cpu = {"meta": {"backend": "cpu", "device_kind": "cpu"}}
-        assert "backend" in backend_mismatch(tpu, cpu)
-        assert backend_mismatch(cpu, cpu) is None
-        # device-kind delta within one backend is also a hardware delta
-        v5 = {"meta": {"backend": "tpu", "device_kind": "TPU v5e"}}
-        assert "device_kind" in backend_mismatch(tpu, v5)
-
-    def test_legacy_docs_compare_on_normalized_backend(self):
-        from benchmarks.check_regression import backend_mismatch
-
-        legacy = {"backend": "cpu (forced host devices; measures program "
-                             "structure, not speedup)"}
-        stamped = {"meta": {"backend": "cpu", "device_kind": "cpu"}}
-        assert backend_mismatch(legacy, stamped) is None  # no bogus skip
-        assert backend_mismatch(legacy, {"meta": {"backend": "tpu"}})
-        assert backend_mismatch({}, stamped) is None  # nothing to compare

@@ -102,7 +102,7 @@ class TestSharedRoundBody:
 
         # engine side: depth-1 FLAT ring holding x^t, everyone pulls slot 0
         ring_round = make_ring_round(_quad_loss, FL)
-        spec = make_flat_spec(params, FL.server_pass_block_n)
+        spec = make_flat_spec(params)
         ring = flatten_tree(spec, params)[None] * 1
         new_p, new_ring, info = ring_round(
             params, ring, jnp.zeros(k, jnp.int32), local, probe, sizes,
@@ -135,7 +135,7 @@ class TestSharedRoundBody:
         params = {"w": jnp.array([1.0, -1.0, 0.5, 2.0], jnp.bfloat16)}
         _, local, probe, sizes, _ = _round_inputs()
         ring_round = make_ring_round(_quad_loss, FL)
-        spec = make_flat_spec(params, FL.server_pass_block_n)
+        spec = make_flat_spec(params)
         ring = flatten_tree(spec, params)[None] * 1
         zeros = jnp.zeros(3, jnp.float32)
         p1, ring, _ = ring_round(params, ring, jnp.zeros(3, jnp.int32),
@@ -228,7 +228,7 @@ class TestStreamingRoundBody:
 
         # exact path: bases crafted so ||x - b_i||^2 == the ring distance
         dists = np.array([float(jnp.sum(norm_ring[:t])) for t in taus])
-        spec = make_flat_spec(params, fl.server_pass_block_n)
+        spec = make_flat_spec(params)
         x = flatten_tree(spec, params)
         onehot = jnp.eye(spec.n_padded)[:k]
         bases = x[None] - jnp.sqrt(jnp.asarray(dists, jnp.float32))[:, None] \

@@ -2,9 +2,9 @@
 contract (DESIGN.md §3).
 
 The reference mode is itself checked against a hand-computed pure-jnp
-oracle built directly from core/weighting, then the Pallas modes
-(batched two-kernel, fused one-kernel) are swept against reference in
-interpret mode across K, non-lane-multiple N, dtypes, and policies.
+oracle built directly from core/weighting, then the Pallas kernels
+(``fused``) are swept against reference in interpret mode across K,
+non-lane-multiple N, dtypes, and policies.
 """
 import jax
 import jax.numpy as jnp
@@ -23,6 +23,7 @@ from repro.core.server_pass import (
     unflatten_like,
 )
 from repro.core.weighting import (
+    POLICIES,
     contribution_weights,
     staleness_degree,
     statistical_effect,
@@ -81,7 +82,7 @@ class TestModeParity:
         fl = FLConfig(weighting="paper")
         case = _flat_case(jax.random.PRNGKey(k), k, 1000)
         ref, dists, w = _oracle(*case, fl)
-        for mode in ("reference", "batched", "fused"):
+        for mode in ("reference", "fused"):
             got, info = _run_mode(mode, *case, fl)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=1e-4, atol=1e-5, err_msg=mode)
@@ -98,13 +99,11 @@ class TestModeParity:
         fl = FLConfig(weighting="paper")
         case = _flat_case(jax.random.PRNGKey(0), 3, n)
         ref, dists, _ = _oracle(*case, fl)
-        for mode in ("batched", "fused"):
-            got, info = _run_mode(mode, *case, fl)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                       rtol=1e-4, atol=1e-5, err_msg=mode)
-            np.testing.assert_allclose(np.asarray(info["sq_dists"]),
-                                       np.asarray(dists), rtol=1e-3,
-                                       err_msg=mode)
+        got, info = _run_mode("fused", *case, fl)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(info["sq_dists"]),
+                                   np.asarray(dists), rtol=1e-3)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_delta_dtypes(self, dtype):
@@ -112,14 +111,12 @@ class TestModeParity:
         case = _flat_case(jax.random.PRNGKey(1), 4, 1000, dtype=dtype)
         ref, _, _ = _oracle(*case, fl)
         tol = 1e-4 if dtype == jnp.float32 else 5e-2
-        for mode in ("reference", "batched", "fused"):
+        for mode in ("reference", "fused"):
             got, _ = _run_mode(mode, *case, fl)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=tol, atol=tol, err_msg=mode)
 
-    @pytest.mark.parametrize("policy", ["paper", "fedbuff", "polynomial",
-                                        "fedasync_constant",
-                                        "fedasync_hinge", "fedasync_poly"])
+    @pytest.mark.parametrize("policy", POLICIES)
     def test_policies_and_mask(self, policy):
         # hinge_b=1.0 puts taus 2..3 past the hinge knee, so the fused
         # kernel's in-kernel reciprocal branch is actually exercised
@@ -127,7 +124,7 @@ class TestModeParity:
         case = _flat_case(jax.random.PRNGKey(2), 4, 520)
         mask = jnp.array([1.0, 0.0, 1.0, 1.0])
         ref, _, w_ref = _oracle(*case, fl, mask=mask)
-        for mode in ("reference", "batched", "fused"):
+        for mode in ("reference", "fused"):
             got, info = _run_mode(mode, *case, fl, mask=mask)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=1e-4, atol=1e-5, err_msg=mode)
@@ -177,15 +174,13 @@ class TestFlatSpecAdapter:
         if jax.default_backend() == "tpu":
             pytest.skip("kernel modes are native on TPU")
         import logging
-        for mode in ("fused", "batched"):
-            caplog.clear()
-            with caplog.at_level(logging.WARNING,
-                                 logger="repro.core.server_pass"):
-                got, interpret = resolve_mode(mode)
-            assert any("compile only for TPU" in r.getMessage()
-                       and r.levelno == logging.WARNING
-                       for r in caplog.records), caplog.records
-            assert got == mode and interpret
+        with caplog.at_level(logging.WARNING,
+                             logger="repro.core.server_pass"):
+            got, interpret = resolve_mode("fused")
+        assert any("compile only for TPU" in r.getMessage()
+                   and r.levelno == logging.WARNING
+                   for r in caplog.records), caplog.records
+        assert got == "fused" and interpret
 
     def test_auto_fallback_is_silent(self, caplog):
         import logging

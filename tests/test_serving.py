@@ -256,7 +256,7 @@ class TestServingParity:
             # exact path on the SAME stream: bases whose eq. 3 distances
             # equal the pre-round ring distances the streaming form used
             dists = np.array([float(jnp.sum(ring[:tt])) for tt in taus])
-            spec = make_flat_spec(x_tree, fl.server_pass_block_n)
+            spec = make_flat_spec(x_tree)
             x = flatten_tree(spec, x_tree)
             onehot = jnp.eye(spec.n_padded)[:k]
             bases = x[None] - jnp.sqrt(

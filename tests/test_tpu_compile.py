@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import FLConfig
 from repro.core.server_pass import apply_server_round
+from repro.core.weighting import POLICIES
 from repro.kernels.ring_codec.kernel import int8_sq_dists_pallas
 from repro.kernels.weighted_agg import kernel as wk
 from repro.kernels.weighted_agg.ops import pad_to
@@ -101,6 +102,19 @@ def test_fused_server_kernel_compiles(one_chip, whisper_np, k, case):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert len(_kernel_calls(text, "fused_server_pallas")) == 1
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_fused_server_kernel_lowers_every_policy(one_chip, policy):
+    """The kernel's weighting step is core/weighting's own code, traced
+    on (K, 1) columns inside the kernel: each policy must lower."""
+    k, n, f32 = 16, 1 << 18, jnp.float32
+    compiled = _compile(
+        lambda x, b, d, p, t, m: wk.fused_server_pallas(
+            x, b, d, p, t, m, policy=policy),
+        one_chip, ((n,), f32), ((k, n), f32), ((k, n), f32), ((k,), f32),
+        ((k,), f32), ((k,), f32))
+    assert len(_kernel_calls(compiled.as_text(), "fused_server_pallas")) == 1
 
 
 @pytest.mark.parametrize("kernel", ["sq_dists", "weighted_sum"])
