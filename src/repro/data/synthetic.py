@@ -36,28 +36,37 @@ class ClientDataset:
         return int(self.x.shape[0])
 
     def batch_indices(self, batch_size: int) -> np.ndarray:
-        """One batch worth of sample indices (replacement iff n < batch)."""
+        """One draw of the sample stream: a batch's indices (replacement
+        iff n < batch). Every batch goes through here, so a subclass
+        that overrides it sees each draw in order."""
         n = self.size
         return self._rng.choice(n, size=batch_size, replace=n < batch_size)
 
+    def batch_rows(self, batch_size: int) -> np.ndarray:
+        """(B,) row numbers of the next ``batch()``."""
+        return self.batch_indices(batch_size)
+
+    def batches_rows(self, batch_size: int, count: int) -> np.ndarray:
+        """(count, B) row numbers of the next ``batches()``: ``count``
+        sequential ``batch_indices`` draws, so the RNG stream is the one
+        ``count`` successive ``batch()`` calls consume."""
+        return np.stack([self.batch_indices(batch_size)
+                         for _ in range(count)])
+
     def batch(self, batch_size: int):
         """Sample a random mini-batch (with replacement if n < batch_size)."""
-        idx = self.batch_indices(batch_size)
+        idx = self.batch_rows(batch_size)
         return self.x[idx], self.y[idx]
 
     def batches(self, batch_size: int, count: int):
         """``count`` stacked mini-batches, (count, B, ...), in ONE gather.
 
-        Index draws are ``count`` sequential ``batch_indices`` calls, so
-        the RNG stream — and therefore every batch — is bit-identical to
-        ``count`` successive ``batch()`` calls; only the per-batch fancy
-        indexing and stacking (the host-side cost at large N) collapses
-        into a single vectorized gather + reshape.
+        Bit-identical to ``count`` successive ``batch()`` calls; only the
+        per-batch fancy indexing and stacking collapses into a single
+        vectorized gather.
         """
-        idx = np.concatenate([self.batch_indices(batch_size)
-                              for _ in range(count)])
-        return (self.x[idx].reshape(count, batch_size, *self.x.shape[1:]),
-                self.y[idx].reshape(count, batch_size, *self.y.shape[1:]))
+        idx = self.batches_rows(batch_size, count)
+        return self.x[idx], self.y[idx]
 
     # -- checkpointing (engine resume) ----------------------------------
     def rng_state(self) -> np.ndarray:

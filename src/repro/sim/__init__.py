@@ -5,6 +5,8 @@
 ``population`` — fully device-resident client STATE machine (counter-based
                  RNG, vmapped behavior kernel, device top-k windows) for
                  million-client scenarios (DESIGN.md §10).
+``pool``       — every client's samples as one device-resident pool, which
+                 both engines gather their batches from.
 ``scenarios``  — registry of named, composable client-behavior models.
 ``traces``     — record/replay of client timelines for exact reproducibility.
 ``metrics``    — staleness / participation / weight-entropy telemetry.

@@ -25,10 +25,14 @@ DESIGN.md §5):
 Because a client's upload timeline never depends on server state (it
 trains, uploads after a sampled duration, immediately re-pulls), the
 host can walk the event heap **ahead of the device**: it pre-computes up
-to ``rounds_per_launch`` windows of (batches, base slots, staleness,
-probes) as stacked ``(S, K, ...)`` arrays and drives all S rounds
+to ``rounds_per_launch`` windows of (batch rows, base slots, staleness,
+probe rows) as stacked ``(S, K, ...)`` arrays and drives all S rounds
 through one ``jax.lax.scan`` launch, the version ring advancing
-on-device between rounds. The round log is fetched once at the end of
+on-device between rounds. The clients' samples sit on the device as one
+pool (``sim/pool.py``), placed once per set of client arrays; the host
+draws each batch's indices from the client's own stream and stages only
+those, and the round gathers ``pool_x[rows]`` inside the scan. The round
+log is fetched once at the end of
 the run — ``jax.device_get`` on one host, process-local addressable
 shards on a process-spanning mesh (DESIGN.md §7) — so a T-round
 simulation costs O(T / rounds_per_launch) launches and O(1) log syncs
@@ -89,6 +93,7 @@ from repro.sim.base import (  # noqa: F401  (re-exported for callers)
     round_log_rows,
     round_log_to_arrays,
 )
+from repro.sim.pool import place_client_pool
 from repro.sim.scenarios import ClientBehavior, LatencyModel, Scenario
 from repro.sim.traces import EventTrace
 
@@ -200,18 +205,20 @@ def _make_chunk_step(loss_fn: Callable, fl: FLConfig,
     ring_round = make_ring_round(loss_fn, fl, mesh=mesh)
 
     @jax.jit
-    def chunk_step(params, ring, base_slots, batches, probes, sizes, taus,
-                   new_slots):
+    def chunk_step(params, ring, pool_x, pool_y, base_slots, train_rows,
+                   probe_rows, sizes, taus, new_slots):
         def round_body(carry, xs):
             params, ring = carry
-            slots, batch, probe, size, tau, new_slot = xs
+            slots, rows, prows, size, tau, new_slot = xs
+            batch = (pool_x[rows], pool_y[rows])     # (K, M, B, ...)
+            probe = (pool_x[prows], pool_y[prows])   # (K, B, ...)
             params, ring, info = ring_round(params, ring, slots, batch,
                                             probe, size, tau, new_slot)
             return (params, ring), info
 
         (params, ring), infos = jax.lax.scan(
             round_body, (params, ring),
-            (base_slots, batches, probes, sizes, taus, new_slots))
+            (base_slots, train_rows, probe_rows, sizes, taus, new_slots))
         return params, ring, infos
 
     return chunk_step
@@ -261,6 +268,15 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
     loss/clients/config/seed) resumes the run BIT-identically to the
     uninterrupted one. ``total_rounds`` always counts from round 0, so a
     resume runs ``total_rounds - init_state.version`` more rounds.
+
+    Every client's samples are placed on the device as one pool, once per
+    set of client ``x``/``y`` arrays (``sim/pool.py``): a resumed or
+    repeated run with the same clients reuses the placed pool. A client's
+    arrays are read, not copied, between runs, so changing them in place
+    between runs is not seen. Samples that do not fit on the device raise.
+    The host draws every batch's indices from the client's own stream
+    (``ClientDataset.batches_rows`` / ``batch_rows``) and stages only
+    those.
     """
     n = len(clients)
     k = fl.buffer_size
@@ -282,12 +298,16 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
     tr = tracer if tracer is not None else default_tracer()
     _dispatches = reg.counter("engine_dispatches_total")
     _syncs = reg.counter("engine_host_syncs_total")
+    _pool_puts = reg.counter("engine_pool_puts_total")
+    _pool_hits = reg.counter("engine_pool_hits_total")
     _dispatches_start = _dispatches.value
 
     with tr.span(SPAN_PREPARE, version=0 if init_state is None
                  else init_state.version) as prep:
         _raw_chunk_step = _make_chunk_step(loss_fn, fl, mesh)
         beh = resolve_behavior(n, seed, behavior, scenario, latency, trace)
+        pool, pool_bytes = place_client_pool(clients, mesh)
+        (_pool_puts if pool_bytes else _pool_hits).inc()
         if init_state is None:
             params = init_params
             _, ring = init_version_ring(init_params, fl, mesh=mesh,
@@ -346,9 +366,9 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
             params = (put_replicated(params, mesh) if spans
                       else jax.device_put(params, jax.sharding.NamedSharding(
                           mesh, jax.sharding.PartitionSpec())))
-        # bytes of the ring rows placed here and of host params, which
-        # the first launch copies
-        prep.set(bytes=put_bytes)
+        # bytes of the ring rows and the pool placed here and of host
+        # params, which the first launch copies
+        prep.set(bytes=put_bytes + pool_bytes)
 
     def chunk_step(*args):
         _dispatches.inc()
@@ -399,28 +419,21 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
         return window
 
     def gather(window):
-        """The window's client batches, stacked (K, ...). One vectorized
-        gather per client for the M local-step batches
-        (ClientDataset.batches draws the same index stream as M
-        sequential .batch() calls); probes draw AFTER all train draws —
-        the aggregation-time order AsyncServer uses — so legacy parity
-        holds. Gathering a launch's windows after walking them keeps
-        each client's draws in order: the walk draws nothing from the
-        clients' batch streams."""
-        train = [clients[cid].batches(fl.batch_size, fl.local_steps)
-                 for _, cid, _, _ in window]
-        probes = [clients[cid].batch(fl.batch_size)
-                  for _, cid, _, _ in window]  # eq.-4 probes, FIFO order
-        return {
-            "batches": tuple(np.stack([b[i] for b in train])
-                             for i in range(2)),
-            "probes": tuple(np.stack([p[i] for p in probes])
-                            for i in range(2)),
-            "base_slots": np.asarray([bv % ring_depth
-                                      for _, _, bv, _ in window], np.int32),
-            "sizes": np.asarray([clients[cid].size
-                                 for _, cid, _, _ in window], np.float32),
-        }
+        """The window's batch indices, each client's own draws, stacked
+        (K, M, B) train and (K, B) probe. Each client draws its M
+        local-step batches from its own stream
+        (``ClientDataset.batches_rows``, the same stream as M sequential
+        ``.batch()`` calls); probes draw AFTER all train draws — the
+        aggregation-time order AsyncServer uses — so legacy parity holds.
+        Gathering a launch's windows after walking them keeps each
+        client's draws in order: the walk draws nothing from the clients'
+        batch streams."""
+        cids = [cid for _, cid, _, _ in window]
+        train = [clients[c].batches_rows(fl.batch_size, fl.local_steps)
+                 for c in cids]
+        probes = [clients[c].batch_rows(fl.batch_size)
+                  for c in cids]  # eq.-4 probes, FIFO order
+        return np.stack(train), np.stack(probes)
 
     if init_state is None:
         # a resumed run's round-0 (and any snapshot-round) eval is
@@ -445,17 +458,27 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
                 reschedule(cid_trigger, t_trigger)
                 windows.append(w)
         with tr.span(SPAN_GATHER, rounds=s):
-            arrays = [gather(w) for w in windows]
+            drawn = [gather(w) for w in windows]
 
         # ---- device: all S rounds in one scanned launch -----------------
         with tr.span(SPAN_STAGE, rounds=s) as stage:
+            cids = np.asarray([[cid for _, cid, _, _ in w] for w in windows])
+            train = np.stack([d[0] for d in drawn])   # (S, K, M, B)
+            probes = np.stack([d[1] for d in drawn])  # (S, K, B)
+            size = pool.sizes[cids]
+            if (min(train.min(), probes.min()) < 0
+                    or (train >= size[..., None, None]).any()
+                    or (probes >= size[..., None]).any()):
+                # the pool would silently read another client's rows
+                raise IndexError("a client drew a batch index outside "
+                                 "its own samples")
+            off = pool.offsets[cids]
             chunk_args = (
-                np.stack([a["base_slots"] for a in arrays]),
-                tuple(np.stack([a["batches"][i] for a in arrays])
-                      for i in range(2)),
-                tuple(np.stack([a["probes"][i] for a in arrays])
-                      for i in range(2)),
-                np.stack([a["sizes"] for a in arrays]),
+                np.asarray([[bv % ring_depth for _, _, bv, _ in w]
+                            for w in windows], np.int32),
+                (train + off[..., None, None]).astype(np.int32),
+                (probes + off[..., None]).astype(np.int32),
+                size.astype(np.float32),
                 np.asarray([[tau for _, _, _, tau in w] for w in windows],
                            np.float32),
                 np.asarray([(version - s + j + 1) % ring_depth
@@ -469,8 +492,9 @@ def run_vectorized(loss_fn: Callable, init_params: Any, clients: Sequence,
                 # locally
                 chunk_args = put_replicated(chunk_args, mesh)
         with tr.span(SPAN_APPLY, rounds=s, version=version):
-            params, ring, infos = chunk_step(params, ring, *chunk_args)
-        # keep only the round-log metadata; the batch arrays would
+            params, ring, infos = chunk_step(params, ring, pool.x, pool.y,
+                                             *chunk_args)
+        # keep only the round-log metadata; the batch rows would
         # otherwise pin O(total_rounds * K * batch) host memory
         pending.append({"windows": [
             {"clients": [cid for _, cid, _, _ in w],

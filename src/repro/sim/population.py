@@ -89,6 +89,7 @@ from repro.sim.base import (
     round_log_to_arrays,
 )
 from repro.sim.engine import init_version_ring
+from repro.sim.pool import DevicePool
 from repro.sim.scenarios import ClientBehavior, Scenario
 
 P = jax.sharding.PartitionSpec
@@ -462,57 +463,6 @@ def make_window_step(sc: Scenario, fl: FLConfig, n: int, seed: int,
 
 
 # ---------------------------------------------------------------------------
-# device data pool
-# ---------------------------------------------------------------------------
-
-
-class DevicePool(NamedTuple):
-    """All clients' samples as one device-resident pool.
-
-    ``x``/``y`` are the concatenated sample arrays; client ``cid`` owns
-    rows ``[offsets[cid], offsets[cid] + sizes[cid])``. Batch indices are
-    counter draws (``_TAG_TRAIN``/``_TAG_PROBE``), so the pool gather for
-    a whole window is one fused op inside the round scan. ``shared``
-    overlaps client slices on a small pool — the layout that keeps a
-    1e6-client sweep in flat host memory.
-    """
-
-    x: Any        # (P, ...) features
-    y: Any        # (P,) labels
-    offsets: Any  # (N,) i32 first row per client
-    sizes: Any    # (N,) i32 rows per client
-
-    @property
-    def num_clients(self) -> int:
-        return int(self.offsets.shape[0])
-
-    @staticmethod
-    def from_clients(clients: Sequence[ClientDataset]) -> "DevicePool":
-        """Concatenate per-client datasets (the small-N parity path)."""
-        sizes = np.asarray([c.size for c in clients], np.int32)
-        offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
-        return DevicePool(
-            x=np.concatenate([np.asarray(c.x) for c in clients]),
-            y=np.concatenate([np.asarray(c.y) for c in clients]),
-            offsets=offsets, sizes=sizes)
-
-    @staticmethod
-    def shared(x: np.ndarray, y: np.ndarray, num_clients: int,
-               samples_per_client: int) -> "DevicePool":
-        """N overlapping client slices over one fixed pool: O(pool) memory
-        independent of N (a prime-stride walk decorrelates neighbors)."""
-        total = int(np.asarray(x).shape[0])
-        if samples_per_client > total:
-            raise ValueError(f"samples_per_client {samples_per_client} "
-                             f"exceeds pool size {total}")
-        span = total - samples_per_client + 1
-        offsets = (np.arange(num_clients, dtype=np.int64) * 7919) % span
-        return DevicePool(x=x, y=y, offsets=offsets.astype(np.int32),
-                          sizes=np.full(num_clients, samples_per_client,
-                                        np.int32))
-
-
-# ---------------------------------------------------------------------------
 # fused chunk: S x (window kernel -> pool gather -> ring round)
 # ---------------------------------------------------------------------------
 
@@ -814,9 +764,10 @@ def _host_index_fns(seed: int, batch_size: int):
 class CounterDataset(ClientDataset):
     """Host twin of the device pool's batch sampling.
 
-    Train batches (``batches``) and probe batches (``batch``) consume
-    separate counter streams — order-independent, so the engine's
-    probes-after-all-train-draws convention and the device's per-accept
+    Train batches (``batches_rows``/``batches``) and probe batches
+    (``batch_rows``/``batch``) consume separate counter streams —
+    order-independent, so the engine's probes-after-all-train-draws
+    convention and the device's per-accept
     draws index identically even when a client appears twice in one
     window. Checkpoint state is the two counters (no PCG64).
     """
@@ -831,24 +782,23 @@ class CounterDataset(ClientDataset):
 
     def batch_indices(self, batch_size: int) -> np.ndarray:
         raise NotImplementedError(
-            "CounterDataset draws are stream-specific: use batch() "
-            "(probe stream) or batches() (train stream)")
+            "CounterDataset draws are stream-specific: use batch_rows() "
+            "(probe stream) or batches_rows() (train stream)")
 
-    def batches(self, batch_size: int, count: int):
+    def batches_rows(self, batch_size: int, count: int) -> np.ndarray:
         fn, _ = _host_index_fns(self.stream_seed, batch_size)
-        idx = np.concatenate([
+        idx = np.stack([
             np.asarray(fn(np.int32(self.cid), np.int32(self._k_train + j),
                           np.int32(self.size))) for j in range(count)])
         self._k_train += count
-        return (self.x[idx].reshape(count, batch_size, *self.x.shape[1:]),
-                self.y[idx].reshape(count, batch_size, *self.y.shape[1:]))
+        return idx
 
-    def batch(self, batch_size: int):
+    def batch_rows(self, batch_size: int) -> np.ndarray:
         _, fn = _host_index_fns(self.stream_seed, batch_size)
         idx = np.asarray(fn(np.int32(self.cid), np.int32(self._k_probe),
                             np.int32(self.size)))
         self._k_probe += 1
-        return self.x[idx], self.y[idx]
+        return idx
 
     def rng_state(self) -> np.ndarray:
         return np.asarray([self._k_train, self._k_probe, 0, 0, 0, 0],

@@ -3,6 +3,7 @@ histogram-quantile accuracy vs numpy, Chrome-trace schema + coverage,
 sink gating, and the migration contracts — ServingController.metrics()
 parity with the registry, the engine's dispatch counter backing
 SimResult.num_launches, and bench provenance compatibility checks."""
+import dataclasses
 import functools
 import json
 import logging
@@ -563,12 +564,13 @@ class TestEngineSpans:
                   local_lr=0.05, batch_size=8, max_staleness=4)
     D = 4
 
-    def _vec(self, fl=None, **kw):
+    def _vec(self, fl=None, clients=None, **kw):
         kw.setdefault("rounds_per_launch", 4)
+        kw.setdefault("registry", MetricsRegistry())
         return functools.partial(
             run_vectorized, _quad_loss, {"w": jnp.zeros(self.D)},
-            _quad_clients(d=self.D), fl or self.FL, seed=0,
-            capture_state=True, registry=MetricsRegistry(), **kw)
+            clients or _quad_clients(d=self.D), fl or self.FL, seed=0,
+            capture_state=True, **kw)
 
     def _pop(self, **kw):
         return functools.partial(
@@ -622,15 +624,15 @@ class TestEngineSpans:
         assert counts == [4 * n + 3 for n in launches]
 
     def test_stage_bytes_are_the_chunk_arguments(self):
+        """A launch stages int32 batch rows into the device pool, never
+        the samples themselves."""
         tr = Tracer(annotate=False)
         self._vec()(total_rounds=8, tracer=tr)
-        fl, d, s = self.FL, self.D, 4
+        fl, s = self.FL, 4
         k, m, b = fl.buffer_size, fl.local_steps, fl.batch_size
         per_round = 4 * (k                    # base slots, int32
-                         + k * m * b * d      # batch x, f32
-                         + k * m * b          # batch y
-                         + k * b * d          # probe x
-                         + k * b              # probe y
+                         + k * m * b          # train batch rows, int32
+                         + k * b              # probe rows, int32
                          + k + k              # sizes, taus
                          + 1)                 # the new ring slot
         stages = [ev for ev in tr.events if ev["name"] == SPAN_STAGE]
@@ -638,14 +640,40 @@ class TestEngineSpans:
 
     def test_prepare_bytes_count_the_restored_state(self):
         tr = Tracer(annotate=False)
-        half = self._vec()(total_rounds=4, tracer=tr)
-        self._vec()(total_rounds=8, tracer=tr, init_state=half.final_state)
+        clients = _quad_clients(d=self.D)
+        half = self._vec(clients=clients)(total_rounds=4, tracer=tr)
+        self._vec(clients=clients)(total_rounds=8, tracer=tr,
+                                   init_state=half.final_state)
         prep = [ev for ev in tr.events if ev["name"] == SPAN_PREPARE]
         st = half.final_state
         ring = np.asarray(st.ring).nbytes
-        # fresh: the start params are already on the device
+        pool = sum(c.x.nbytes + c.y.nbytes for c in clients)
+        # fresh: the start params are already on the device, the pool is
+        # placed; resumed: the pool is the one placed before
         assert [ev["args"]["bytes"] for ev in prep] == [
-            0, ring + np.asarray(st.params["w"]).nbytes]
+            pool, ring + np.asarray(st.params["w"]).nbytes]
+
+    def test_resume_places_the_pool_once(self):
+        reg = MetricsRegistry()
+        clients = _quad_clients(d=self.D)
+        half = self._vec(clients=clients)(total_rounds=4, registry=reg)
+        self._vec(clients=clients)(total_rounds=8, registry=reg,
+                                   init_state=half.final_state)
+        snap = reg.snapshot()
+        assert snap["engine_pool_puts_total"] == 1.0
+        assert snap["engine_pool_hits_total"] == 1.0
+
+    def test_new_client_arrays_place_a_new_pool(self):
+        reg = MetricsRegistry()
+        clients = _quad_clients(d=self.D)
+        self._vec(clients=clients)(total_rounds=4, registry=reg)
+        # same samples, new arrays: the pool is placed again
+        fresh = [dataclasses.replace(c, x=c.x.copy(), y=c.y.copy())
+                 for c in clients]
+        self._vec(clients=fresh)(total_rounds=4, registry=reg)
+        snap = reg.snapshot()
+        assert snap["engine_pool_puts_total"] == 2.0
+        assert snap.get("engine_pool_hits_total", 0.0) == 0.0
 
     @pytest.mark.parametrize("engine", ["vectorized", "population"])
     def test_resume_stays_bit_identical(self, engine):

@@ -393,3 +393,94 @@ class TestScenarioComposability:
                              total_rounds=2, scenario=composed, seed=0,
                              record_trace=True)
         assert res.server_rounds == 2
+
+
+@dataclasses.dataclass
+class _RecordingClient(ClientDataset):
+    """Records every ``batch_indices`` draw as ``(cid, indices)``."""
+
+    cid: int = 0
+    rows: list = dataclasses.field(default_factory=list)
+
+    def batch_indices(self, batch_size):
+        idx = super().batch_indices(batch_size)
+        self.rows.append((self.cid, idx))
+        return idx
+
+
+class TestDevicePool:
+    """The engine keeps the clients' samples on the device and stages
+    only the indices each client draws from its own stream."""
+
+    def test_subclass_sees_every_draw_in_window_order(self):
+        log = []
+        clients = [_RecordingClient(c.x, c.y, c.seed, cid=i, rows=log)
+                   for i, c in enumerate(_quad_clients())]
+        k, m = FL.buffer_size, FL.local_steps
+        res = run_vectorized(_quad_loss, _params(), clients, FL,
+                             total_rounds=6, seed=0, rounds_per_launch=4)
+        per_round = k * (m + 1)
+        assert len(log) == per_round * 6
+        for r, row in enumerate(res.round_log):
+            drawn = [c for c, _ in log[per_round * r:per_round * (r + 1)]]
+            # train batches client by client, then the probes
+            assert drawn == [c for c in row["clients"] for _ in range(m)] \
+                + list(row["clients"])
+
+    def test_engine_batches_equal_host_gathers(self):
+        """What the round trains and probes on is exactly
+        ``ClientDataset.batches``/``batch`` gathered on the host from the
+        same RNG state."""
+        seen = []
+
+        def record(x, y):
+            seen.append((np.asarray(x).copy(), np.asarray(y).copy()))
+
+        def loss(params, batch):
+            jax.debug.callback(record, *batch)
+            return _quad_loss(params, batch)
+
+        res = run_vectorized(loss, _params(), _quad_clients(), FL,
+                             total_rounds=5, seed=0, rounds_per_launch=4)
+        host = _quad_clients()
+        want = []
+        for row in res.round_log:
+            for c in row["clients"]:
+                xs, ys = host[c].batches(FL.batch_size, FL.local_steps)
+                want.extend(zip(xs, ys))
+            want.extend(host[c].batch(FL.batch_size) for c in row["clients"])
+
+        def key(b):
+            return b[0].tobytes() + b[1].tobytes()
+
+        assert len(seen) == len(want)
+        assert sorted(map(key, seen)) == sorted(map(key, want))
+
+    def test_dropped_clients_free_the_pool(self):
+        import gc
+        import weakref
+
+        from repro.sim.pool import place_client_pool
+
+        clients = _quad_clients()
+        placed, put = place_client_pool(clients)
+        again, put_again = place_client_pool(clients)
+        assert put > 0 and put_again == 0 and again is placed
+        ref = weakref.ref(placed.x)
+        del placed, again, clients
+        gc.collect()
+        assert ref() is None
+
+    def test_out_of_range_draw_raises(self):
+        """An index past a client's samples would read its neighbour's
+        rows in the pool: the engine refuses it."""
+
+        @dataclasses.dataclass
+        class PastTheEnd(ClientDataset):
+            def batch_indices(self, batch_size):
+                return np.full(batch_size, self.size)
+
+        clients = [PastTheEnd(c.x, c.y, c.seed) for c in _quad_clients()]
+        with pytest.raises(IndexError):
+            run_vectorized(_quad_loss, _params(), clients, FL,
+                           total_rounds=2, seed=0)
